@@ -232,6 +232,9 @@ def bench_precision(total_chunks: int, reps: int, max_iters: int):
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="fewer chunks/reps (CI smoke)")

@@ -114,6 +114,9 @@ def run_cell(spec, *, seed: int, data_root: str | None, eps_tol: float,
 
 
 def main(argv=None) -> int:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     from repro.evalsuite import datasets, gate
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])

@@ -32,6 +32,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main(fast: bool = False, topology: str = "single"):
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     from repro.api import BigMeansConfig, evaluate, fit
     from repro.data.synthetic import GMMSpec, gmm_dataset
 

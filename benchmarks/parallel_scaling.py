@@ -51,6 +51,9 @@ print("RESULT " + json.dumps(out))
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
     proc = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                           capture_output=True, text=True, timeout=1800)

@@ -153,6 +153,9 @@ def bench_smoke(outdir):
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     choices=["tables", "chunk_sweep", "kernels"])

@@ -378,6 +378,9 @@ def _acceptance(clean: dict, chaos: dict, bitwise: dict) -> dict:
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="smaller streams (CI smoke)")

@@ -219,6 +219,9 @@ def _acceptance(rows: list[dict]) -> dict:
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="fewer clients/requests (CI smoke)")

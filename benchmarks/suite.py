@@ -24,6 +24,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main(argv=None) -> int:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--quick", action="store_true",
                     help="the PR-gate tier: small datasets, 2 seeds "

@@ -1,5 +1,5 @@
 """The paper's own workload as a dry-runnable config: Big-means on a
-HEPMASS-scale stream (m=10.5M, n=27, k=25, s=64000 — the paper's largest
+HEPMASS-scale stream (m=10.5M, n=28, k=25, s=64000 — the paper's largest
 setting), two-level decomposition on the production mesh.
 
 The algorithm knobs live in one place — an embedded
@@ -41,7 +41,7 @@ class BigMeansWorkload:
     }
 
     def __init__(self, name: str = "bigmeans_paper", family: str = "cluster",
-                 m: int = 10_500_000, n_features: int = 27,
+                 m: int = 10_500_000, n_features: int = 28,
                  algo: BigMeansConfig | None = None, **legacy):
         self.name = name
         self.family = family

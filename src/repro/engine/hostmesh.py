@@ -541,6 +541,21 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
+def holds_accelerator() -> bool:
+    """True when this process has already initialized a non-CPU backend.
+
+    Never initializes one itself: a process that has not touched JAX's
+    backends yet is not holding a chip.
+    """
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return False
+    return any(name != "cpu" for name in xla_bridge.backends())
+
+
 def launch_local(cmd, num_hosts: int, *, timeout_s: float = 300.0,
                  env_extra: dict | None = None) -> list[HostProc]:
     """Spawn ``num_hosts`` processes of ``cmd`` on this machine with the
@@ -549,8 +564,17 @@ def launch_local(cmd, num_hosts: int, *, timeout_s: float = 300.0,
 
     ``cmd`` is an argv list, or a callable ``rank -> argv list``.  Output
     (stdout+stderr, merged) is captured per rank; processes still running
-    after ``timeout_s`` are killed and reported with returncode -9.
+    after ``timeout_s`` are killed and reported with returncode -9.  The
+    children inherit this process's environment as-is (``JAX_PLATFORMS``
+    included), and a parent that already holds an accelerator is refused:
+    a chip belongs to one process, so its children could not get it.
     """
+    if holds_accelerator():
+        raise RuntimeError(
+            "launch_local: this process has already initialized an "
+            "accelerator backend and holds its chips, so the host "
+            "processes it would start cannot get them. Launch them from "
+            "a parent that has not touched JAX.")
     coord = f"127.0.0.1:{_free_port()}"
     procs = []
     for r in range(num_hosts):
@@ -559,7 +583,6 @@ def launch_local(cmd, num_hosts: int, *, timeout_s: float = 300.0,
         env[ENV_COORD] = coord
         env[ENV_NUM_HOSTS] = str(num_hosts)
         env[ENV_RANK] = str(r)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         argv = cmd(r) if callable(cmd) else list(cmd)
         procs.append(subprocess.Popen(
             argv, env=env, stdout=subprocess.PIPE,

@@ -59,12 +59,7 @@ def _cast_dataset(X, precision):
     return px.cast_storage(X, precision)
 
 
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:   # jax < 0.6: experimental API, `check_rep` instead of `check_vma`
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    _shard_map = functools.partial(_experimental_shard_map, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -261,8 +256,6 @@ def worker_sharded(
         widx = jax.lax.axis_index(axes[0])
         if len(axes) > 1:
             for a in axes[1:]:
-                # mesh.shape is static — avoids jax.lax.axis_size, which
-                # older jax versions lack inside shard_map.
                 widx = widx * mesh.shape[a] + jax.lax.axis_index(a)
         key = jax.random.fold_in(key, widx)
         state = init_state(k, x_local.shape[1])

@@ -45,6 +45,7 @@ Two stream-state modes share the loop:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -278,6 +279,7 @@ class _StepKernel:
         self.cfg = cfg
         self.key = key
         self.topology = topology
+        self._mesh_step = None
 
     def _kwargs(self):
         cfg = self.cfg
@@ -301,8 +303,27 @@ class _StepKernel:
         if mesh and chunks.shape[0] % self.topology.devices == 0:
             chunks, states, keys = _mesh_put(
                 self.topology, (chunks, states, keys))
+            return self._sharded_step()(chunks, states, keys)
         return bigmeans.chunk_step_batched(
             chunks, states, keys, **self._kwargs())
+
+    def _sharded_step(self):
+        """The batched step with each device running its own streams.
+
+        A ``shard_map`` rather than a sharded ``jit``: XLA cannot partition
+        the Pallas kernels inside the step by itself, and the streams are
+        independent, so each device simply steps its slice of them.
+        """
+        if self._mesh_step is None:
+            from jax.sharding import PartitionSpec as P
+
+            spec = P(self.topology.axis)
+            step = functools.partial(bigmeans.chunk_step_batched,
+                                     **self._kwargs())
+            self._mesh_step = jax.jit(jax.shard_map(
+                step, mesh=self.topology.mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False))
+        return self._mesh_step
 
     def step_fold(self, state, pending):
         """Advance one incumbent by len(pending) concurrent chunk streams."""

@@ -149,6 +149,18 @@ def run_suite(
         methods = [m for m in METHODS if tier in m.tiers]
     if not specs or not methods or not seeds:
         raise ValueError("empty sweep: need >=1 dataset, method and seed")
+    fleets = [m.name for m in methods if m.runner == "host2p"]
+    if fleets:
+        import jax
+
+        if jax.default_backend() != "cpu":
+            # This process fits in-process cells, so it holds the chip;
+            # the fleets' child processes could not get it.
+            raise RuntimeError(
+                f"cells {fleets} start one JAX process per host, which "
+                f"cannot share the {jax.default_backend()} chip this suite "
+                "process holds; run them with JAX_PLATFORMS=cpu or leave "
+                "them out with method_names")
 
     rows: list[dict] = []
     dataset_records = []

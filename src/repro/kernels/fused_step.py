@@ -23,9 +23,9 @@ batched variants alike.
 Mixed precision (``precision='bf16'``): the chunk and centroids are stored
 and streamed bf16 — halving the remaining HBM bytes again — and both MXU
 contractions take bf16 operands.  Everything that decides or accumulates is
-f32: the score accumulator (``preferred_element_type``), ``||c||^2`` /
-``||x||^2`` (computed from the full-width view before the storage cast),
-sums, counts and the objective.  ``'bf16x3'`` keeps f32 storage and runs
+f32: the score accumulator (``preferred_element_type``), ``||c||^2``
+(from the full-width centroids), ``||x||^2`` (of the stored points), sums,
+counts and the objective.  ``'bf16x3'`` keeps f32 storage and runs
 each contraction as three compensated bf16 products (near-f32 numerics at
 bf16 MXU rates; no bandwidth change).
 
@@ -84,6 +84,12 @@ _BLOCK_N = 512                 # contraction tile for the distance matmul
 
 PIPELINES = ("blocks", "dma")
 
+# Scoped VMEM for the kernels (v5e has 128 MiB per core).  The compiler's
+# default limit refuses the envelope's wide corners — n = 4096 with
+# k_pad = 256 in f32, or block_m = 512 at n = 2048 — which fit in 64 MiB
+# (tests/test_tpu_compile.py).
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 2**20)
+
 
 def _pad_to(a, size, axis, value=0.0):
     pad = size - a.shape[axis]
@@ -106,6 +112,13 @@ def _batched_tiles(k: int, n: int, block_k: int | None = None,
     return k_pad, n_pad, block_k, block_n
 
 
+def _lane_tiles(row, k_pad: int, block_k: int, value=0.0):
+    """Pad per-centroid rows ``[..., k]`` to ``k_pad`` and lay them out
+    tile-major as ``[..., k_pad // block_k, 1, block_k]``."""
+    row = _pad_to(row, k_pad, row.ndim - 1, value=value)
+    return row.reshape(row.shape[:-1] + (k_pad // block_k, 1, block_k))
+
+
 def fits(k: int, n: int) -> bool:
     k_pad, n_pad, _, _ = _batched_tiles(k, n)
     return k <= MAX_K and n <= MAX_N and k_pad * n_pad <= _MAX_KN_ELEMS
@@ -123,10 +136,14 @@ def _tile_argmin(x, c, csq, *, block_k: int, block_n: int, precision: str,
                  t=None, scale=None):
     """Running (min, argmin) across k lane tiles for one resident point tile.
 
-    ``x`` [bm, n_pad], ``c`` [k_pad, n_pad], ``csq`` [1, k_pad]; under int8
-    ``t`` [1, k_pad] are the per-row centroid scales and ``scale`` [1, n_pad]
-    the per-feature chunk scales.  Returns (bidx int32 [bm], best f32 [bm],
-    xsq f32 [bm]).  Both tile loops are unrolled at trace time.
+    ``x`` [bm, n_pad], ``c`` [k_pad, n_pad], ``csq`` [nk, 1, block_k]; under
+    int8 ``t`` [nk, 1, block_k] are the per-row centroid scales and ``scale``
+    [1, n_pad] the per-feature chunk scales.  The per-centroid rows come
+    tile-major (see :func:`_lane_tiles`) so tile ``j`` is a leading-axis
+    index, never a lane slice at an offset: Mosaic cannot broadcast a
+    ``(1, block_k)`` slice taken at lane offset ``j * block_k > 0``.
+    Returns (bidx int32 [bm], best f32 [bm], xsq f32 [bm]).  Both tile
+    loops are unrolled at trace time.
     """
     bm, n_pad = x.shape
     k_pad = c.shape[0]
@@ -143,15 +160,14 @@ def _tile_argmin(x, c, csq, *, block_k: int, block_n: int, precision: str,
                 sl = slice(u * block_n, (u + 1) * block_n)
                 idots += px.intdot(x[:, sl], ct[:, sl],
                                    (((1,), (1,)), ((), ())))
-            dots = (idots.astype(jnp.float32)
-                    * t[0:1, j * block_k:(j + 1) * block_k])
+            dots = idots.astype(jnp.float32) * t[j]
         else:
             dots = jnp.zeros((bm, block_k), jnp.float32)
             for u in range(nn):
                 sl = slice(u * block_n, (u + 1) * block_n)
                 dots += px.dot(x[:, sl], ct[:, sl], (((1,), (1,)), ((), ())),
                                precision)
-        sc = csq[0:1, j * block_k:(j + 1) * block_k] - 2.0 * dots
+        sc = csq[j] - 2.0 * dots
         tmin = jnp.min(sc, axis=1)
         targ = jnp.argmin(sc, axis=1).astype(jnp.int32) + j * block_k
         take = tmin < best
@@ -337,10 +353,10 @@ def fused_step_pallas(
 
     xp = _pad_to(_pad_to(xs, bm, 0), n_pad, 1)
     cp = _pad_to(_pad_to(cs, k_pad, 0), n_pad, 1)
-    csqp = _pad_to(csq[None, :], k_pad, 1, value=_BIG)
-    inputs = [xp, cp, csqp]
+    nk = k_pad // block_k
+    inputs = [xp, cp, _lane_tiles(csq, k_pad, block_k, value=_BIG)]
     if int8:
-        inputs += [_pad_to(t[None, :], k_pad, 1),
+        inputs += [_lane_tiles(t, k_pad, block_k),
                    _pad_to(qx.scale[None, :], n_pad, 1)]
 
     sums_dtype = jnp.int32 if int8 else jnp.float32
@@ -354,11 +370,11 @@ def fused_step_pallas(
 
     if pipeline == "dma":
         num_tiles = bm // block_m
-        x_spec = [pl.BlockSpec(memory_space=pltpu.ANY)]
+        x_spec = [pl.BlockSpec(memory_space=pl.ANY)]
         aux_specs = [pl.BlockSpec((k_pad, n_pad), lambda: (0, 0)),
-                     pl.BlockSpec((1, k_pad), lambda: (0, 0))]
+                     pl.BlockSpec((nk, 1, block_k), lambda: (0, 0, 0))]
         if int8:
-            aux_specs += [pl.BlockSpec((1, k_pad), lambda: (0, 0)),
+            aux_specs += [pl.BlockSpec((nk, 1, block_k), lambda: (0, 0, 0)),
                           pl.BlockSpec((1, n_pad), lambda: (0, 0))]
         sums, counts, obj = pl.pallas_call(
             functools.partial(_fused_dma_kernel, num_tiles=num_tiles, **kw),
@@ -373,16 +389,17 @@ def fused_step_pallas(
                 pltpu.VMEM((2, block_m, n_pad), xp.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
         )(*inputs)
     else:
         in_specs = [
             pl.BlockSpec((block_m, n_pad), lambda i: (i, 0)),
             pl.BlockSpec((k_pad, n_pad), lambda i: (0, 0)),
-            pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
+            pl.BlockSpec((nk, 1, block_k), lambda i: (0, 0, 0)),
         ]
         if int8:
-            in_specs += [pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
+            in_specs += [pl.BlockSpec((nk, 1, block_k), lambda i: (0, 0, 0)),
                          pl.BlockSpec((1, n_pad), lambda i: (0, 0))]
         sums, counts, obj = pl.pallas_call(
             functools.partial(_fused_kernel, **kw),
@@ -394,6 +411,7 @@ def fused_step_pallas(
                 pl.BlockSpec((1, 1), lambda i: (0, 0)),
             ],
             out_shape=out_shape,
+            compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
         )(*inputs)
 
@@ -471,17 +489,18 @@ def fused_step_batched_pallas(
 
     xp = _pad_to(_pad_to(xs, bm, 1), n_pad, 2)
     cp = _pad_to(_pad_to(cs, k_pad, 1), n_pad, 2)
-    csqp = _pad_to(csq[:, None, :], k_pad, 2, value=_BIG)
-    inputs = [xp, cp, csqp]
+    nk = k_pad // block_k
+    inputs = [xp, cp, _lane_tiles(csq, k_pad, block_k, value=_BIG)]
+    tiles_spec = pl.BlockSpec((1, nk, 1, block_k), lambda b, i: (b, 0, 0, 0))
     in_specs = [
         pl.BlockSpec((1, block_m, n_pad), lambda b, i: (b, i, 0)),
         pl.BlockSpec((1, k_pad, n_pad), lambda b, i: (b, 0, 0)),
-        pl.BlockSpec((1, 1, k_pad), lambda b, i: (b, 0, 0)),
+        tiles_spec,
     ]
     if int8:
-        inputs += [_pad_to(t[:, None, :], k_pad, 2),
+        inputs += [_lane_tiles(t, k_pad, block_k),
                    _pad_to(qx.scale[:, None, :], n_pad, 2)]
-        in_specs += [pl.BlockSpec((1, 1, k_pad), lambda b, i: (b, 0, 0)),
+        in_specs += [tiles_spec,
                      pl.BlockSpec((1, 1, n_pad), lambda b, i: (b, 0, 0))]
 
     sums_dtype = jnp.int32 if int8 else jnp.float32
@@ -501,6 +520,7 @@ def fused_step_batched_pallas(
             jax.ShapeDtypeStruct((batch, 1, k_pad), jnp.float32),
             jax.ShapeDtypeStruct((batch, 1, 1), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
 

@@ -31,7 +31,11 @@ Graceful degradation: a Pallas dispatch that raises demotes that
 ``(op, impl, shape, precision)`` to the ref path once per process (recorded
 in :func:`kernel_demotions`, surfaced as a ``RuntimeWarning`` and as
 ``("kernel_fallback", ...)`` trace events by ``repro.api.fit``) — a kernel
-bug degrades a long run instead of killing it.
+bug degrades a long run instead of killing it.  A Pallas ``fused_step``
+request whose ``(k, n)`` lies outside the fused kernel's envelope runs the
+two-pass path and is recorded the same way, so an empty
+:func:`kernel_demotions` means every Pallas request ran its Pallas kernel
+(``chip_smoke.py`` fails on any entry).
 """
 from __future__ import annotations
 
@@ -88,6 +92,13 @@ def record_demotion(op: str, impl: str, shape: tuple, precision: str,
     key = (op, impl, tuple(shape), precision)
     if not _demoted(key):
         _demote(key, exc)
+
+
+def _record_envelope_miss(op: str, impl: str, shape: tuple,
+                          precision: str) -> None:
+    """A Pallas request routed to the two-pass path by the envelope."""
+    record_demotion(op, impl, shape, precision, ValueError(
+        f"(k, n) = {shape[2:]} is outside the fused kernel envelope"))
 
 
 def _demoted(key: tuple) -> bool:
@@ -305,7 +316,9 @@ def fused_step(
     impl = resolve_impl(impl)
     precision = px.resolve(precision, x.dtype)
     k, n = c.shape[0], c.shape[1]
-    if weights is None and fused.fits(k, n):
+    if weights is None and not fused.fits(k, n):
+        _record_envelope_miss("fused", impl, (1, x.shape[0], k, n), precision)
+    elif weights is None:
         if impl in ("pallas", "pallas_interpret"):
             dkey = ("fused", impl, (1, x.shape[0], k, n), precision)
             if not _demoted(dkey):
@@ -380,23 +393,25 @@ def fused_step_batched(
     precision = px.resolve(precision, x.dtype)
     batch, m = x.shape[0], x.shape[1]
     k, n = c.shape[1], c.shape[2]
-    if fused.fits_batched(k, n):
-        if impl in ("pallas", "pallas_interpret"):
-            dkey = ("fused_batched", impl, (batch, m, k, n), precision)
-            if not _demoted(dkey):
-                try:
-                    interp = impl == "pallas_interpret"
-                    blocks = autotune.get_blocks(
-                        "fused_batched",
-                        _bench(x, lambda blk: lambda: jax.block_until_ready(
-                            fused.fused_step_batched_pallas(
-                                x, c, precision=precision, interpret=interp,
-                                **blk))),
-                        backend=_tune_backend(impl), b=batch, m=m, k=k, n=n,
-                        precision=precision)
-                    return fused.fused_step_batched_pallas(
-                        x, c, precision=precision, interpret=interp, **blocks)
-                except Exception as exc:
-                    _demote(dkey, exc)
-                # demoted shape: the batched two-pass oracle below
+    if not fused.fits_batched(k, n):
+        _record_envelope_miss("fused_batched", impl, (batch, m, k, n),
+                              precision)
+    elif impl in ("pallas", "pallas_interpret"):
+        dkey = ("fused_batched", impl, (batch, m, k, n), precision)
+        if not _demoted(dkey):
+            try:
+                interp = impl == "pallas_interpret"
+                blocks = autotune.get_blocks(
+                    "fused_batched",
+                    _bench(x, lambda blk: lambda: jax.block_until_ready(
+                        fused.fused_step_batched_pallas(
+                            x, c, precision=precision, interpret=interp,
+                            **blk))),
+                    backend=_tune_backend(impl), b=batch, m=m, k=k, n=n,
+                    precision=precision)
+                return fused.fused_step_batched_pallas(
+                    x, c, precision=precision, interpret=interp, **blocks)
+            except Exception as exc:
+                _demote(dkey, exc)
+            # demoted shape: the batched two-pass oracle below
     return _fused_step_batched_ref(x, c, precision=precision)

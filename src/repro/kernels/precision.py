@@ -151,10 +151,15 @@ def dot(a: jax.Array, b: jax.Array, dimension_numbers, precision: str):
             "px.dot has no generic int8 path: the per-feature/per-row scale "
             "algebra is contraction-specific. Use quantize_chunk / "
             "quantize_centroids / intdot explicitly (see ref.py oracles).")
-    dg = lambda x, y: jax.lax.dot_general(  # noqa: E731
-        x, y, dimension_numbers, preferred_element_type=jnp.float32)
+    dg = lambda x, y, p=None: jax.lax.dot_general(  # noqa: E731
+        x, y, dimension_numbers, precision=p,
+        preferred_element_type=jnp.float32)
     if precision == "f32":
-        return dg(a.astype(jnp.float32), b.astype(jnp.float32))
+        # HIGHEST: XLA:TPU's DEFAULT for f32 operands is one bf16 pass,
+        # which is the cancellation the f32 policy exists to avoid.  The
+        # CPU computes full f32 either way.
+        return dg(a.astype(jnp.float32), b.astype(jnp.float32),
+                  jax.lax.Precision.HIGHEST)
     if precision == "bf16":
         return dg(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16))
     a = a.astype(jnp.float32)

@@ -24,7 +24,9 @@ def pairwise_sqdist_ref(x, c: jax.Array,
 
     The dominant matmul runs under the ``precision`` policy (bf16 data at
     half the bytes, optional bf16x3 compensation); ``||x||^2`` / ``||c||^2``
-    are always f32.  ``x2`` (optional [m,1]) lets callers hoist the point
+    are always f32, ``||x||^2`` taken of the points in their storage dtype
+    (what the Pallas kernels stream) and ``||c||^2`` of the full-width
+    centroids.  ``x2`` (optional [m,1]) lets callers hoist the point
     norms out of loops that probe many candidate centroid sets (K-means++
     seeding reads the chunk once per slot instead of twice).
 
@@ -48,7 +50,8 @@ def pairwise_sqdist_ref(x, c: jax.Array,
         # and kernel agree bitwise, not just to rounding.
         return jnp.maximum((c2 - 2.0 * dots) + x2, 0.0)
     if x2 is None:
-        x2 = px.sqnorm(x, keepdims=True)
+        # ||x||^2 of the stored points, as the kernels see them.
+        x2 = px.sqnorm(x.astype(px.storage_dtype(prec)), keepdims=True)
     c2 = px.sqnorm(c)[None, :]
     dots = px.dot(x, c, (((1,), (1,)), ((), ())), prec)
     d = x2 - 2.0 * dots + c2

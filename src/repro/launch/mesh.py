@@ -7,22 +7,13 @@ slice); multi-pod: 2 pods x 256 = 512 chips with a leading "pod" axis.
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:     # jax < 0.6: axes are implicitly Auto
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes, devices=None):
-    """`jax.make_mesh` with Auto axis types where the API supports them —
-    the portable entry point for tests and benchmark subprocesses."""
-    kwargs = _axis_kwargs(len(axes))
+    """`jax.make_mesh` with Auto axis types — the entry point for tests and
+    benchmark subprocesses."""
+    kwargs = {"axis_types": (AxisType.Auto,) * len(axes)}
     if devices is not None:
         kwargs["devices"] = devices
     return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)

@@ -17,12 +17,15 @@ import argparse
 
 import numpy as np
 
-from repro.api import BigMeansConfig, TopologySpec, fit
+from repro.api import BigMeansConfig, FitResult, TopologySpec, fit
 from repro.data.synthetic import GMMSpec, gmm_chunk
+from repro.launch import compile_cache
 from repro.models.registry import get_config
 
 
-def main() -> None:
+def main(argv=None) -> FitResult:
+    """Parse ``argv`` (default: the command line), run, return the fit."""
+    compile_cache.configure()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bigmeans_paper")
     ap.add_argument("--chunks", type=int, default=200)
@@ -41,7 +44,7 @@ def main() -> None:
     ap.add_argument("--rank", type=int, default=None,
                     help="host_mesh: this process's rank (else "
                          "REPRO_HOST_RANK)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     assert cfg.family == "cluster", "use dryrun.py / examples for LM archs"
@@ -69,6 +72,7 @@ def main() -> None:
           f"accepted={result.n_accepted}/{result.n_chunks} "
           f"failed={failed} wall={result.wall_time_s:.1f}s "
           f"n_d={result.n_dist_evals:.3e}")
+    return result
 
 
 if __name__ == "__main__":

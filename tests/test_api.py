@@ -365,3 +365,16 @@ def test_facade_emits_no_warnings():
     with warnings.catch_warnings():
         warnings.simplefilter("error", DeprecationWarning)
         fit(X, CFG, method="sequential")
+
+
+def test_library_import_configures_no_compile_cache():
+    """Only entry points place JAX's persistent compile cache
+    (repro.launch.compile_cache.configure); importing the library leaves
+    whatever the environment says."""
+    import os
+
+    import repro.api  # noqa: F401
+    import repro.launch.train  # noqa: F401
+
+    assert jax.config.jax_compilation_cache_dir == os.environ.get(
+        "JAX_COMPILATION_CACHE_DIR")

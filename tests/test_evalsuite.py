@@ -324,6 +324,15 @@ class TestSuiteRun:
             suite.run_suite("quick",
                             method_names=["bm/seqential", "baseline/forgy"])
 
+    def test_host_fleet_cells_refused_on_an_accelerator(self, monkeypatch):
+        """The suite process holds the chip, so its 2-process cells could
+        not get it: refused before any cell runs."""
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="bm/hostmesh-2p"):
+            suite.run_suite("quick", method_names=["bm/hostmesh-2p"])
+
     def test_method_matrix_meets_acceptance(self):
         """The quick tier must cover >= 2 big-means strategies and >= 3
         baselines (ISSUE 5 acceptance criteria)."""

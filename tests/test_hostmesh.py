@@ -155,6 +155,30 @@ def test_launch_local_env_contract():
     assert outs[0]["coord"].startswith("127.0.0.1:")
 
 
+def test_launch_local_children_inherit_platform(monkeypatch):
+    """No platform is forced on the children: they see the parent's."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    script = ("import os; print('RESULT ' + "
+              "os.environ.get('JAX_PLATFORMS', '<unset>'))")
+    procs = hostmesh.launch_local([sys.executable, "-c", script], 1,
+                                  timeout_s=60)
+    assert procs[0].output.splitlines()[-1] == "RESULT cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    procs = hostmesh.launch_local([sys.executable, "-c", script], 1,
+                                  timeout_s=60)
+    assert procs[0].output.splitlines()[-1] == "RESULT <unset>"
+
+
+def test_launch_local_refuses_when_parent_holds_accelerator(monkeypatch):
+    import jax
+
+    jax.devices()                       # the CPU backend, initialized
+    assert not hostmesh.holds_accelerator()
+    monkeypatch.setattr(hostmesh, "holds_accelerator", lambda: True)
+    with pytest.raises(RuntimeError, match="holds its chips"):
+        hostmesh.launch_local([sys.executable, "-c", "pass"], 2)
+
+
 # ---------------------------------------------------------------------------
 # real 2-process runs
 # ---------------------------------------------------------------------------

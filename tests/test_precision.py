@@ -629,3 +629,57 @@ def test_fit_surfaces_cache_ignored_event_in_trace(tmp_path, clean_autotune):
            if isinstance(t, tuple) and isinstance(t[0], str)
            and t[0] == "autotune_cache_ignored"]
     assert evs and evs[0][1] == str(cache), res.trace[-5:]
+
+
+# ---------------------------------------------------------------------------
+# f32 means f32 on every backend; no silent leaving of the Pallas path
+# ---------------------------------------------------------------------------
+
+
+def test_f32_contractions_request_highest_precision():
+    """XLA:TPU's DEFAULT precision for f32 operands is one bf16 pass; the
+    f32 policy must ask for HIGHEST (the CPU computes f32 either way, so
+    only the request itself can be checked here)."""
+    a = jnp.ones((8, 4))
+    dn = (((1,), (1,)), ((), ()))
+
+    def precisions(policy):
+        jaxpr = jax.make_jaxpr(lambda x: px.dot(x, x, dn, policy))(a)
+        return [e.params["precision"] for e in jaxpr.eqns
+                if e.primitive.name == "dot_general"]
+
+    assert precisions("f32") == [(jax.lax.Precision.HIGHEST,) * 2]
+    assert precisions("bf16") == [None]
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16", "bf16x3"])
+def test_ref_point_norms_of_stored_points(precision):
+    """The oracle's ||x||^2 is that of the points as stored under the
+    policy — what the kernels stream — whatever dtype the caller passed."""
+    x, c = _blobs(m=300, n=28, k=25)
+    stored = x.astype(px.storage_dtype(precision))
+    np.testing.assert_array_equal(
+        np.asarray(ref.pairwise_sqdist_ref(x, c, precision=precision)),
+        np.asarray(ref.pairwise_sqdist_ref(stored, c, precision=precision)))
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_pallas_envelope_miss_is_recorded(clean_demotions, batched):
+    """A Pallas request routed to the two-pass path by the envelope shows
+    in kernel_demotions(), naming op and shape; a ref request does not."""
+    from repro.kernels.fused_step import MAX_K
+
+    k, n, m = MAX_K + 8, 8, 64
+    x = jax.random.normal(jax.random.PRNGKey(0), (m, n))
+    c = jax.random.normal(jax.random.PRNGKey(1), (k, n))
+    if batched:
+        x, c = x[None], c[None]
+    step = ops.fused_step_batched if batched else ops.fused_step
+    step(x, c, impl="ref")
+    assert ops.kernel_demotions() == []
+    with pytest.warns(RuntimeWarning, match="envelope"):
+        step(x, c, impl="pallas_interpret")
+    (demo,) = ops.kernel_demotions()
+    assert demo["op"] == ("fused_batched" if batched else "fused")
+    assert demo["shape"] == (1, m, k, n)
+    assert "envelope" in demo["error"]
