@@ -17,10 +17,13 @@ importable, but documented usage goes through this facade.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 
 import jax
+from jax.profiler import TraceAnnotation
 
+from repro import spans
 from repro.api import baselines as baselines
 from repro.api import sources as sources
 from repro.api import strategies as strategies
@@ -73,6 +76,7 @@ __all__ = [
     "list_baselines",
     "list_methods",
     "list_strategies",
+    "lower_fit",
     "register_baseline",
     "register_strategy",
     "resolve_auto",
@@ -146,6 +150,52 @@ def _resolve_method(method: str):
     raise KeyError(f"unknown method {method!r}; known: {list_methods()}")
 
 
+def _resolve_config(config: BigMeansConfig | None,
+                    overrides: dict) -> BigMeansConfig:
+    if config is None:
+        missing = {"k", "s"} - set(overrides)
+        if missing:
+            raise TypeError(
+                f"fit() without a config needs {sorted(missing)} "
+                "(e.g. fit(X, k=25, s=16384))")
+        return BigMeansConfig(**overrides)
+    return config.replace(**overrides) if overrides else config
+
+
+def _resolve_source(cfg: BigMeansConfig, data, n_features: int | None):
+    from repro.engine import topology as topo_lib
+
+    if topo_lib.requested_kind(cfg) == "host_mesh":
+        # jax.distributed.initialize() must run before the first JAX
+        # computation in the process (the PRNG key below already is one),
+        # so multi-host configs bootstrap the process group here.
+        # Idempotent: resolve() reuses an already-initialized group.
+        topo_lib.resolve(cfg.topology)
+    return as_source(data, n_features=n_features)
+
+
+@contextlib.contextmanager
+def _tuning(cfg: BigMeansConfig, source):
+    """Scoped to one call (exception paths included): with
+    ``cfg.autotune`` the tuner times candidate kernel tilings for this
+    fit's shapes eagerly (off the jit path) and caches the winners (see
+    repro.kernels.autotune); results are unaffected.  The previous enable
+    state is restored afterwards so a later fit with autotune=False never
+    pays surprise timing sweeps."""
+    if not cfg.autotune:
+        yield
+        return
+    from repro.kernels import autotune
+
+    prev = autotune.enabled()
+    autotune.enable(True)
+    try:
+        _pretune(cfg, source)
+        yield
+    finally:
+        autotune.enable(prev)
+
+
 def fit(
     data,
     config: BigMeansConfig | None = None,
@@ -170,86 +220,95 @@ def fit(
       data whose first chunk should not be probed eagerly.
 
     ``wall_time_s`` on the result covers the whole call, compile included.
+    A profiler capture shows the call as the host spans
+    ``repro.fit.dispatch`` (until the jitted call returns) and
+    ``repro.fit.collect`` (reading its result), and its device operations
+    under the ``repro.fit.*`` scopes (see :mod:`repro.spans`).
     """
-    if config is None:
-        missing = {"k", "s"} - set(overrides)
-        if missing:
-            raise TypeError(
-                f"fit() without a config needs {sorted(missing)} "
-                "(e.g. fit(X, k=25, s=16384))")
-        cfg = BigMeansConfig(**overrides)
-    else:
-        cfg = config.replace(**overrides) if overrides else config
-
-    from repro.engine import topology as topo_lib
-
-    if topo_lib.requested_kind(cfg) == "host_mesh":
-        # jax.distributed.initialize() must run before the first JAX
-        # computation in the process (the PRNG key below already is one),
-        # so multi-host configs bootstrap the process group here.
-        # Idempotent: resolve() reuses an already-initialized group.
-        topo_lib.resolve(cfg.topology)
-
-    source = as_source(data, n_features=n_features)
-    prev_tuning = None
+    cfg = _resolve_config(config, overrides)
     from repro.kernels import autotune as _autotune
+    from repro.kernels import ops as _ops
 
-    # Snapshot before any kernel work: the disk cache loads lazily on the
-    # first get_blocks lookup, which may happen inside _pretune below.
-    n_tune_events = len(_autotune.events())
-    try:
-        if cfg.autotune:
-            # Scoped to this call (exception paths included): the tuner
-            # times candidate kernel tilings for this fit's shapes eagerly
-            # (off the jit path) and caches the winners (see
-            # repro.kernels.autotune); results are unaffected.  The
-            # previous enable state is restored afterwards so a later fit
-            # with autotune=False never pays surprise timing sweeps.
-            from repro.kernels import autotune
+    with TraceAnnotation(spans.FIT_DISPATCH, strategy=method,
+                         n_chunks=cfg.n_chunks):
+        source = _resolve_source(cfg, data, n_features)
+        # Snapshot before any kernel work: the disk cache loads lazily on
+        # the first get_blocks lookup, which may happen inside _pretune.
+        n_tune_events = len(_autotune.events())
+        with _tuning(cfg, source):
+            fn = _resolve_method(method)
+            if key is None:
+                key = jax.random.PRNGKey(cfg.seed)
+            n_demotions = len(_ops.kernel_demotions())
+            t0 = time.monotonic()
+            program = strategies.plan(method, cfg, source, key)
+            if program is None:
+                result = fn(cfg, source, key)
+            else:
+                out = program.dispatch()
+    if program is None:
+        jax.block_until_ready(result.centroids)
+    else:
+        result = strategies.collect(program, out)
+        if method == "auto":
+            result.extras["auto"] = True
+    result.wall_time_s = time.monotonic() - t0
+    # Graceful kernel degradation taken during this call surfaces on
+    # the result: trace events + the run-health summary.
+    fallbacks = _ops.kernel_demotions()[n_demotions:]
+    for d in fallbacks:
+        result.trace.append(("kernel_fallback", d["op"], d["error"]))
+    # Likewise for autotune-cache files that were ignored (corrupt or
+    # stale schema): never fatal, but never silent either.
+    for ev in _autotune.events()[n_tune_events:]:
+        result.trace.append(ev)
+    if fallbacks:
+        result.extras.setdefault("health", {})["kernel_fallbacks"] = \
+            fallbacks
+    # Suite hook: how this fit was actually dispatched, in one
+    # JSON-safe record (evalsuite and benchmarks read it off
+    # `FitResult.to_row()` instead of re-deriving resolution logic).
+    result.extras["fit"] = {
+        "method": method,
+        "impl": cfg.resolved_impl(),
+        "precision": cfg.precision,
+        "autotune": cfg.autotune,
+        "seed": int(cfg.seed),
+        "source": type(source).__name__,
+    }
+    return result
 
-            prev_tuning = autotune.enabled()
-            autotune.enable(True)
-            _pretune(cfg, source)
-        fn = _resolve_method(method)
+
+def lower_fit(
+    data,
+    config: BigMeansConfig | None = None,
+    *,
+    method: str = "auto",
+    key: jax.Array | None = None,
+    n_features: int | None = None,
+    **overrides,
+) -> jax.stages.Lowered:
+    """Lower, without running it, the jitted program that :func:`fit` runs
+    with the same arguments.
+
+    Its ``compile().as_text()`` names each device operation of a profile of
+    that fit by its instruction; :func:`repro.spans.op_scopes` maps the
+    names to the ``repro.fit.*`` scopes.  Only strategies that run as one
+    jitted call have such a program (``sequential``, ``batched``, and
+    ``auto`` where it picks one of them); any other raises ValueError.
+    """
+    cfg = _resolve_config(config, overrides)
+    source = _resolve_source(cfg, data, n_features)
+    with _tuning(cfg, source):
+        _resolve_method(method)
         if key is None:
             key = jax.random.PRNGKey(cfg.seed)
-
-        from repro.kernels import ops as _ops
-
-        n_demotions = len(_ops.kernel_demotions())
-        t0 = time.monotonic()
-        result = fn(cfg, source, key)
-        jax.block_until_ready(result.centroids)
-        result.wall_time_s = time.monotonic() - t0
-        # Graceful kernel degradation taken during this call surfaces on
-        # the result: trace events + the run-health summary.
-        fallbacks = _ops.kernel_demotions()[n_demotions:]
-        for d in fallbacks:
-            result.trace.append(("kernel_fallback", d["op"], d["error"]))
-        # Likewise for autotune-cache files that were ignored (corrupt or
-        # stale schema): never fatal, but never silent either.
-        for ev in _autotune.events()[n_tune_events:]:
-            result.trace.append(ev)
-        if fallbacks:
-            result.extras.setdefault("health", {})["kernel_fallbacks"] = \
-                fallbacks
-        # Suite hook: how this fit was actually dispatched, in one
-        # JSON-safe record (evalsuite and benchmarks read it off
-        # `FitResult.to_row()` instead of re-deriving resolution logic).
-        result.extras["fit"] = {
-            "method": method,
-            "impl": cfg.resolved_impl(),
-            "precision": cfg.precision,
-            "autotune": cfg.autotune,
-            "seed": int(cfg.seed),
-            "source": type(source).__name__,
-        }
-    finally:
-        if prev_tuning is not None:
-            from repro.kernels import autotune
-
-            autotune.enable(prev_tuning)
-    return result
+        program = strategies.plan(method, cfg, source, key)
+        if program is None:
+            raise ValueError(
+                f"method {method!r} does not run as one jitted program here; "
+                "lower_fit covers 'sequential' and 'batched'")
+        return program.lower()
 
 
 def evaluate(result_or_centroids, data) -> tuple[jax.Array, float]:

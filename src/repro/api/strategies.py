@@ -34,18 +34,40 @@ first: set ``config.scheduler='competitive_s'`` on the streaming strategy).
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import spans
 from repro.api.config import BigMeansConfig
 from repro.api.result import FitResult
 from repro.api.sources import DataSource
 
 StrategyFn = Callable[[BigMeansConfig, DataSource, jax.Array], FitResult]
 
+
+class Program(NamedTuple):
+    """A fit that runs as one jitted call: the call, and how its
+    ``(state, infos)`` become a :class:`FitResult`."""
+
+    fn: Callable            # a ``jax.jit`` function
+    args: tuple
+    kwargs: dict
+    collect: Callable       # (state, infos) -> FitResult
+
+    def dispatch(self):
+        return self.fn(*self.args, **self.kwargs)
+
+    def lower(self) -> jax.stages.Lowered:
+        return self.fn.lower(*self.args, **self.kwargs)
+
+
+PlanFn = Callable[[BigMeansConfig, DataSource, jax.Array], Program]
+
 _STRATEGIES: dict[str, StrategyFn] = {}
+_PLANS: dict[str, PlanFn] = {}
 
 
 def register_strategy(name: str):
@@ -54,6 +76,38 @@ def register_strategy(name: str):
         _STRATEGIES[name] = fn
         return fn
     return deco
+
+
+def register_program(name: str):
+    """Decorator: register ``plan(config, source, key) -> Program`` for a
+    strategy that runs as one jitted call; the strategy ``name`` dispatches
+    the planned program and collects its result."""
+    def deco(plan_fn: PlanFn) -> PlanFn:
+        def run(cfg, source, key):
+            program = plan_fn(cfg, source, key)
+            return collect(program, program.dispatch())
+
+        _PLANS[name] = plan_fn
+        _STRATEGIES[name] = run
+        return plan_fn
+    return deco
+
+
+def plan(method: str, cfg: BigMeansConfig, source: DataSource,
+         key: jax.Array) -> Program | None:
+    """The one jitted program that ``method`` (``auto`` resolved) runs for
+    this fit, or None where it runs several or is a baseline."""
+    name = resolve_auto(cfg, source) if method == "auto" else method
+    plan_fn = _PLANS.get(name)
+    return None if plan_fn is None else plan_fn(cfg, source, key)
+
+
+def collect(program: Program, out) -> FitResult:
+    """Read a dispatched program's output ``(state, infos)`` to the host."""
+    with TraceAnnotation(spans.FIT_COLLECT):
+        result = program.collect(*out)
+        jax.block_until_ready(result.centroids)
+    return result
 
 
 def get_strategy(name: str) -> StrategyFn:
@@ -129,25 +183,26 @@ def _largest_divisor_le(n: int, cap: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-@register_strategy("sequential")
-def _fit_sequential(cfg: BigMeansConfig, source: DataSource,
-                    key: jax.Array) -> FitResult:
-    from repro.core import bigmeans
+@register_program("sequential")
+def _plan_sequential(cfg: BigMeansConfig, source: DataSource,
+                     key: jax.Array) -> Program:
+    from repro.engine import incore
 
     X = _require_array(source, "sequential")
-    state, infos = bigmeans.big_means(
-        X, key, k=cfg.k, s=cfg.s, n_chunks=cfg.n_chunks,
-        max_iters=cfg.max_iters, tol=cfg.tol, candidates=cfg.candidates,
-        impl=cfg.impl, with_replacement=cfg.with_replacement,
-        precision=cfg.precision)
-    return _result_from_state(state, infos, cfg, "sequential")
+    return Program(
+        incore.sequential, (X, key),
+        dict(k=cfg.k, s=cfg.s, n_chunks=cfg.n_chunks,
+             max_iters=cfg.max_iters, tol=cfg.tol, candidates=cfg.candidates,
+             impl=cfg.impl, with_replacement=cfg.with_replacement,
+             precision=cfg.precision),
+        lambda state, infos: _result_from_state(
+            state, infos, cfg, "sequential"))
 
 
-@register_strategy("batched")
-def _fit_batched(cfg: BigMeansConfig, source: DataSource,
-                 key: jax.Array) -> FitResult:
-    from repro.core import bigmeans
-
+@register_program("batched")
+def _plan_batched(cfg: BigMeansConfig, source: DataSource,
+                  key: jax.Array) -> Program:
+    from repro.engine import incore
     from repro.engine import topology as topo_lib
 
     if cfg.n_chunks % cfg.batch:
@@ -173,14 +228,20 @@ def _fit_batched(cfg: BigMeansConfig, source: DataSource,
             f"divide batch ({cfg.batch})")
 
     X = _require_array(source, "batched")
-    state, infos = bigmeans.big_means_batched(
-        X, key, k=cfg.k, s=cfg.s, batch=cfg.batch, rounds=rounds,
+    kwargs = dict(
+        k=cfg.k, s=cfg.s, batch=cfg.batch, rounds=rounds,
         sync_every=sync_every, max_iters=cfg.max_iters, tol=cfg.tol,
         candidates=cfg.candidates, impl=cfg.impl,
-        with_replacement=cfg.with_replacement, precision=cfg.precision,
-        mesh=mesh, stream_axis=stream_axis)
-    return _result_from_state(
-        state, infos, cfg, "batched", batch=cfg.batch, rounds=rounds)
+        with_replacement=cfg.with_replacement, precision=cfg.precision)
+    if mesh is None:
+        fn = incore.batched_local
+    else:
+        fn = incore.batched_stream_mesh
+        kwargs.update(mesh=mesh, stream_axis=stream_axis)
+    return Program(
+        fn, (X, key), kwargs,
+        lambda state, infos: _result_from_state(
+            state, infos, cfg, "batched", batch=cfg.batch, rounds=rounds))
 
 
 @register_strategy("sharded")

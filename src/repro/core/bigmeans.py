@@ -33,6 +33,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core import kmeans, kmeanspp
 
 
@@ -84,39 +85,43 @@ def chunk_step(
     # Seeding is the identity when no slot is degenerate, so the whole probe
     # loop is skipped at runtime in that (steady-state) case — on CPU the
     # D^2 probes are the dominant per-chunk cost.
-    c_init = jax.lax.cond(
-        jnp.any(state.degenerate),
-        lambda: kmeanspp.seed(
-            points, key, k,
-            init=state.centroids,
-            degenerate=state.degenerate,
-            candidates=candidates,
-        ),
-        lambda: state.centroids.astype(jnp.float32),
-    )
+    with jax.named_scope(spans.FIT_SEED):
+        c_init = jax.lax.cond(
+            jnp.any(state.degenerate),
+            lambda: kmeanspp.seed(
+                points, key, k,
+                init=state.centroids,
+                degenerate=state.degenerate,
+                candidates=candidates,
+            ),
+            lambda: state.centroids.astype(jnp.float32),
+        )
     # line 8: local search
-    res = kmeans.lloyd(points, c_init, max_iters=max_iters, tol=tol, impl=impl,
-                       precision=precision)
+    with jax.named_scope(spans.FIT_LLOYD):
+        res = kmeans.lloyd(points, c_init, max_iters=max_iters, tol=tol,
+                           impl=impl, precision=precision)
 
     # lines 9-11: keep the best (objectives of equal-size chunks compared)
-    accepted = res.objective < state.f_best
-    n_deg = jnp.sum(state.degenerate)
-    n_d = state.n_dist_evals + jnp.float32(s) * (
-        jnp.float32(k) * (res.iterations + 2) + jnp.float32(candidates) * n_deg
-    )
-    new_state = BigMeansState(
-        centroids=jnp.where(accepted, res.centroids, state.centroids),
-        degenerate=jnp.where(accepted, res.degenerate, state.degenerate),
-        f_best=jnp.where(accepted, res.objective, state.f_best),
-        n_accepted=state.n_accepted + accepted.astype(jnp.int32),
-        n_dist_evals=n_d,
-    )
-    info = ChunkInfo(
-        f_new=res.objective,
-        accepted=accepted,
-        lloyd_iters=res.iterations,
-        n_degenerate=jnp.sum(res.degenerate),
-    )
+    with jax.named_scope(spans.FIT_KEEP):
+        accepted = res.objective < state.f_best
+        n_deg = jnp.sum(state.degenerate)
+        n_d = state.n_dist_evals + jnp.float32(s) * (
+            jnp.float32(k) * (res.iterations + 2)
+            + jnp.float32(candidates) * n_deg
+        )
+        new_state = BigMeansState(
+            centroids=jnp.where(accepted, res.centroids, state.centroids),
+            degenerate=jnp.where(accepted, res.degenerate, state.degenerate),
+            f_best=jnp.where(accepted, res.objective, state.f_best),
+            n_accepted=state.n_accepted + accepted.astype(jnp.int32),
+            n_dist_evals=n_d,
+        )
+        info = ChunkInfo(
+            f_new=res.objective,
+            accepted=accepted,
+            lloyd_iters=res.iterations,
+            n_degenerate=jnp.sum(res.degenerate),
+        )
     return new_state, info
 
 
@@ -129,11 +134,12 @@ def sample_chunk(
     indistinguishable and the replacement-free path costs an O(m) permutation.
     """
     m = X.shape[0]
-    if with_replacement:
-        idx = jax.random.randint(key, (s,), 0, m)
-    else:
-        idx = jax.random.choice(key, m, (s,), replace=False)
-    return jnp.take(X, idx, axis=0)
+    with jax.named_scope(spans.FIT_SAMPLE):
+        if with_replacement:
+            idx = jax.random.randint(key, (s,), 0, m)
+        else:
+            idx = jax.random.choice(key, m, (s,), replace=False)
+        return jnp.take(X, idx, axis=0)
 
 
 def big_means(
@@ -188,34 +194,36 @@ def reduce_state(
     """Argmin-reduce B streams into one incumbent (in-core `_exchange_best`,
     degenerate mask included).  Counters are summed across streams — they
     count work done, not who won — and added onto ``base`` when given."""
-    winner = jnp.argmin(states.f_best)
-    n_acc = jnp.sum(states.n_accepted)
-    n_d = jnp.sum(states.n_dist_evals)
-    if base is not None:
-        n_acc = n_acc + base.n_accepted
-        n_d = n_d + base.n_dist_evals
-    return BigMeansState(
-        centroids=states.centroids[winner],
-        degenerate=states.degenerate[winner],
-        f_best=states.f_best[winner],
-        n_accepted=n_acc,
-        n_dist_evals=n_d,
-    )
+    with jax.named_scope(spans.FIT_KEEP):
+        winner = jnp.argmin(states.f_best)
+        n_acc = jnp.sum(states.n_accepted)
+        n_d = jnp.sum(states.n_dist_evals)
+        if base is not None:
+            n_acc = n_acc + base.n_accepted
+            n_d = n_d + base.n_dist_evals
+        return BigMeansState(
+            centroids=states.centroids[winner],
+            degenerate=states.degenerate[winner],
+            f_best=states.f_best[winner],
+            n_accepted=n_acc,
+            n_dist_evals=n_d,
+        )
 
 
 def _sync_streams(states: BigMeansState) -> BigMeansState:
     """Give every stream the winner's incumbent; counters stay per-stream."""
-    winner = jnp.argmin(states.f_best)
-    batch = states.f_best.shape[0]
+    with jax.named_scope(spans.FIT_KEEP):
+        winner = jnp.argmin(states.f_best)
+        batch = states.f_best.shape[0]
 
-    def tile(a):
-        return jnp.broadcast_to(a[winner], (batch,) + a.shape[1:])
+        def tile(a):
+            return jnp.broadcast_to(a[winner], (batch,) + a.shape[1:])
 
-    return states._replace(
-        centroids=tile(states.centroids),
-        degenerate=tile(states.degenerate),
-        f_best=tile(states.f_best),
-    )
+        return states._replace(
+            centroids=tile(states.centroids),
+            degenerate=tile(states.degenerate),
+            f_best=tile(states.f_best),
+        )
 
 
 @functools.partial(
@@ -246,42 +254,45 @@ def chunk_step_batched(
 
     # Same runtime skip as `chunk_step`: when no stream has a degenerate
     # slot (the steady state) the batched probe loop is bypassed entirely.
-    c_init = jax.lax.cond(
-        jnp.any(states.degenerate),
-        lambda: kmeanspp.seed_batched(
-            points, keys, k,
-            init=states.centroids,
-            degenerate=states.degenerate,
-            candidates=candidates,
-        ),
-        lambda: states.centroids.astype(jnp.float32),
-    )
-    res = kmeans.lloyd_batched(
-        points, c_init, max_iters=max_iters, tol=tol, impl=impl,
-        precision=precision,
-    )
+    with jax.named_scope(spans.FIT_SEED):
+        c_init = jax.lax.cond(
+            jnp.any(states.degenerate),
+            lambda: kmeanspp.seed_batched(
+                points, keys, k,
+                init=states.centroids,
+                degenerate=states.degenerate,
+                candidates=candidates,
+            ),
+            lambda: states.centroids.astype(jnp.float32),
+        )
+    with jax.named_scope(spans.FIT_LLOYD):
+        res = kmeans.lloyd_batched(
+            points, c_init, max_iters=max_iters, tol=tol, impl=impl,
+            precision=precision,
+        )
 
-    accepted = res.objective < states.f_best                    # [B]
-    n_deg = jnp.sum(states.degenerate, axis=1)                  # [B]
-    n_d = states.n_dist_evals + jnp.float32(s) * (
-        jnp.float32(k) * (res.iterations + 2)
-        + jnp.float32(candidates) * n_deg
-    )
-    new_states = BigMeansState(
-        centroids=jnp.where(
-            accepted[:, None, None], res.centroids, states.centroids),
-        degenerate=jnp.where(
-            accepted[:, None], res.degenerate, states.degenerate),
-        f_best=jnp.where(accepted, res.objective, states.f_best),
-        n_accepted=states.n_accepted + accepted.astype(jnp.int32),
-        n_dist_evals=n_d,
-    )
-    info = ChunkInfo(
-        f_new=res.objective,
-        accepted=accepted,
-        lloyd_iters=res.iterations,
-        n_degenerate=jnp.sum(res.degenerate, axis=1),
-    )
+    with jax.named_scope(spans.FIT_KEEP):
+        accepted = res.objective < states.f_best                # [B]
+        n_deg = jnp.sum(states.degenerate, axis=1)              # [B]
+        n_d = states.n_dist_evals + jnp.float32(s) * (
+            jnp.float32(k) * (res.iterations + 2)
+            + jnp.float32(candidates) * n_deg
+        )
+        new_states = BigMeansState(
+            centroids=jnp.where(
+                accepted[:, None, None], res.centroids, states.centroids),
+            degenerate=jnp.where(
+                accepted[:, None], res.degenerate, states.degenerate),
+            f_best=jnp.where(accepted, res.objective, states.f_best),
+            n_accepted=states.n_accepted + accepted.astype(jnp.int32),
+            n_dist_evals=n_d,
+        )
+        info = ChunkInfo(
+            f_new=res.objective,
+            accepted=accepted,
+            lloyd_iters=res.iterations,
+            n_degenerate=jnp.sum(res.degenerate, axis=1),
+        )
     return new_states, info
 
 
@@ -346,15 +357,16 @@ def big_means_batched(
 
 def _exchange_best(state: BigMeansState, axis: str) -> BigMeansState:
     """Keep-the-best across workers: tiny argmin-all-reduce on (f, C)."""
-    f_all = jax.lax.all_gather(state.f_best, axis)            # [W]
-    winner = jnp.argmin(f_all)
-    c_all = jax.lax.all_gather(state.centroids, axis)         # [W, k, n]
-    deg_all = jax.lax.all_gather(state.degenerate, axis)      # [W, k]
-    return state._replace(
-        centroids=c_all[winner],
-        degenerate=deg_all[winner],
-        f_best=f_all[winner],
-    )
+    with jax.named_scope(spans.FIT_KEEP):
+        f_all = jax.lax.all_gather(state.f_best, axis)        # [W]
+        winner = jnp.argmin(f_all)
+        c_all = jax.lax.all_gather(state.centroids, axis)     # [W, k, n]
+        deg_all = jax.lax.all_gather(state.degenerate, axis)  # [W, k]
+        return state._replace(
+            centroids=c_all[winner],
+            degenerate=deg_all[winner],
+            f_best=f_all[winner],
+        )
 
 
 def big_means_sharded(

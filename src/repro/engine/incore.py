@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import spans
 from repro.core.bigmeans import (
     BigMeansState,
     ChunkInfo,
@@ -118,7 +119,8 @@ def stream_scan(X, states, keys, *, s, max_iters, tol, candidates, impl,
     exchanges incumbents at each sync boundary."""
 
     def body(states, keys_i):                       # keys_i [batch, ...]
-        split = jax.vmap(jax.random.split)(keys_i)  # [batch, 2, ...]
+        with jax.named_scope(spans.FIT_SAMPLE):
+            split = jax.vmap(jax.random.split)(keys_i)  # [batch, 2, ...]
         ks, kc = split[:, 0], split[:, 1]
         chunks = jax.vmap(
             lambda kk: sample_chunk(X, kk, s, with_replacement=with_replacement)
@@ -182,17 +184,19 @@ def batched_stream_mesh(
     def sync(states):
         """Global keep-the-best: local winner, then argmin-all-gather
         across devices; every stream continues from the global winner."""
-        w = jnp.argmin(states.f_best)
-        f_all = jax.lax.all_gather(states.f_best[w], stream_axis)      # [D]
-        c_all = jax.lax.all_gather(states.centroids[w], stream_axis)
-        d_all = jax.lax.all_gather(states.degenerate[w], stream_axis)
-        g = jnp.argmin(f_all)
-        bl = states.f_best.shape[0]
-        return states._replace(
-            centroids=jnp.broadcast_to(c_all[g], states.centroids.shape),
-            degenerate=jnp.broadcast_to(d_all[g], states.degenerate.shape),
-            f_best=jnp.broadcast_to(f_all[g], (bl,)),
-        )
+        with jax.named_scope(spans.FIT_KEEP):
+            w = jnp.argmin(states.f_best)
+            f_all = jax.lax.all_gather(states.f_best[w], stream_axis)  # [D]
+            c_all = jax.lax.all_gather(states.centroids[w], stream_axis)
+            d_all = jax.lax.all_gather(states.degenerate[w], stream_axis)
+            g = jnp.argmin(f_all)
+            bl = states.f_best.shape[0]
+            return states._replace(
+                centroids=jnp.broadcast_to(c_all[g], states.centroids.shape),
+                degenerate=jnp.broadcast_to(d_all[g],
+                                            states.degenerate.shape),
+                f_best=jnp.broadcast_to(f_all[g], (bl,)),
+            )
 
     def worker(x_rep, keys_local):          # [outer, sync, batch/D, ...]
         states = broadcast_state(init_state(k, n), keys_local.shape[2])
@@ -203,17 +207,18 @@ def batched_stream_mesh(
             precision=precision,
         )
         local = reduce_state(states)
-        f_all = jax.lax.all_gather(local.f_best, stream_axis)
-        c_all = jax.lax.all_gather(local.centroids, stream_axis)
-        d_all = jax.lax.all_gather(local.degenerate, stream_axis)
-        g = jnp.argmin(f_all)
-        final = BigMeansState(
-            centroids=c_all[g],
-            degenerate=d_all[g],
-            f_best=f_all[g],
-            n_accepted=jax.lax.psum(local.n_accepted, stream_axis),
-            n_dist_evals=jax.lax.psum(local.n_dist_evals, stream_axis),
-        )
+        with jax.named_scope(spans.FIT_KEEP):
+            f_all = jax.lax.all_gather(local.f_best, stream_axis)
+            c_all = jax.lax.all_gather(local.centroids, stream_axis)
+            d_all = jax.lax.all_gather(local.degenerate, stream_axis)
+            g = jnp.argmin(f_all)
+            final = BigMeansState(
+                centroids=c_all[g],
+                degenerate=d_all[g],
+                f_best=f_all[g],
+                n_accepted=jax.lax.psum(local.n_accepted, stream_axis),
+                n_dist_evals=jax.lax.psum(local.n_dist_evals, stream_axis),
+            )
         return final, infos
 
     shard = _shard_map(

@@ -39,13 +39,14 @@ import time
 from concurrent.futures import Future
 from dataclasses import dataclass
 
-import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import spans
 from repro.engine import faults
 from repro.serve import resilience
 from repro.serve.config import ServeConfig, _next_pow2
-from repro.serve.registry import ModelEntry
+from repro.serve.registry import LAUNCH_ID, ModelEntry
 from repro.serve.resilience import (
     CircuitBreaker,
     DeadlineExceeded,
@@ -74,7 +75,10 @@ class AssignResponse:
     ``version`` / ``step`` identify the exact centroid snapshot that
     served this response (one snapshot per response, by construction);
     ``batch_rows`` / ``n_coalesced`` describe the launch it rode in;
-    ``latency_ms`` is submit-to-completion, queueing and linger included.
+    ``latency_ms`` is submit-to-completion, queueing and linger included;
+    ``queue_ms`` is its part from submit to being taken off the queue;
+    ``launch`` is the launch's id, the ``launch`` arg of its
+    ``repro.serve.*`` profiler spans.
     """
 
     ids: np.ndarray         # [m] int32 cluster ids
@@ -85,16 +89,20 @@ class AssignResponse:
     latency_ms: float
     batch_rows: int         # padded bucket rows of the launch
     n_coalesced: int        # requests coalesced into the launch
+    queue_ms: float         # submit to dequeue into a batch
+    launch: int             # id of the launch it rode in
 
 
 class _Request:
-    __slots__ = ("points", "future", "t_submit", "deadline", "tenant")
+    __slots__ = ("points", "future", "t_submit", "t_dequeue", "deadline",
+                 "tenant")
 
     def __init__(self, points: np.ndarray, *, deadline: float | None = None,
                  tenant: str = "default"):
         self.points = points
         self.future: Future = Future()
         self.t_submit = time.monotonic()
+        self.t_dequeue = self.t_submit
         self.deadline = deadline           # absolute monotonic, or None
         self.tenant = tenant
 
@@ -111,6 +119,9 @@ class BatcherStats:
     def __init__(self, maxlen: int = 20000):
         self.lock = threading.Lock()
         self.latencies_ms = collections.deque(maxlen=maxlen)
+        self.queue_ms = collections.deque(maxlen=maxlen)
+        # host time of each launch, pack to scatter (one per n_batches)
+        self.launch_ms = collections.deque(maxlen=maxlen)
         self.n_requests = 0
         self.n_rejected = 0          # QueueFull
         self.n_quota_rejected = 0    # QuotaExceeded (per-tenant)
@@ -126,16 +137,19 @@ class BatcherStats:
         self.n_points = 0
         self.n_padded_rows = 0
 
-    def record_batch(self, reqs: list, bucket: int) -> None:
+    def record_batch(self, reqs: list, bucket: int,
+                     launch_ms: float) -> None:
         with self.lock:
             self.n_batches += 1
+            self.launch_ms.append(launch_ms)
             rows = sum(r.points.shape[0] for r in reqs)
             self.n_points += rows
             self.n_padded_rows += bucket - rows
 
-    def record_latency(self, ms: float) -> None:
+    def record_latency(self, ms: float, queue_ms: float) -> None:
         with self.lock:
             self.latencies_ms.append(ms)
+            self.queue_ms.append(queue_ms)
 
     def bump(self, counter: str, by: int = 1) -> None:
         with self.lock:
@@ -144,6 +158,8 @@ class BatcherStats:
     def to_dict(self) -> dict:
         with self.lock:
             lat = np.asarray(self.latencies_ms, dtype=np.float64)
+            queue = np.asarray(self.queue_ms, dtype=np.float64)
+            launch = np.asarray(self.launch_ms, dtype=np.float64)
             out = {
                 "n_requests": self.n_requests,
                 "n_rejected": self.n_rejected,
@@ -166,6 +182,10 @@ class BatcherStats:
             out["p50_ms"] = float(np.percentile(lat, 50))
             out["p99_ms"] = float(np.percentile(lat, 99))
             out["mean_ms"] = float(lat.mean())
+            out["queue_p99_ms"] = float(np.percentile(queue, 99))
+        if launch.size:
+            out["launch_p50_ms"] = float(np.percentile(launch, 50))
+            out["launch_p99_ms"] = float(np.percentile(launch, 99))
         return out
 
 
@@ -184,6 +204,7 @@ class Batcher:
         self._inflight: list[_Request] = []
         self._bucket_fail_streak: collections.Counter = collections.Counter()
         self.stats = BatcherStats()
+        self._n_launches = 0
         self._trace_cb = trace
         self.events: list = []
         self.breaker = CircuitBreaker(
@@ -330,6 +351,7 @@ class Batcher:
 
     def _dequeue_locked(self) -> _Request:
         req = self._queue.popleft()
+        req.t_dequeue = time.monotonic()
         self._tenant_pending[req.tenant] -= 1
         self._inflight.append(req)
         return req
@@ -376,36 +398,42 @@ class Batcher:
         b = max(_next_pow2(rows), self._buckets[0])
         return min(b, self._buckets[-1])
 
-    def _pack(self, batch: list[_Request], n_features: int
-              ) -> tuple[np.ndarray, int]:
-        rows = sum(r.points.shape[0] for r in batch)
-        bucket = self._bucket_for(rows)
+    def _pack(self, batch: list[_Request], bucket: int, n_features: int
+              ) -> np.ndarray:
         buf = np.zeros((bucket, n_features), dtype=np.float32)
         off = 0
         for r in batch:
             m = r.points.shape[0]
             buf[off:off + m] = r.points
             off += m
-        return buf, bucket
+        return buf
 
-    def _scatter(self, batch, ids, dists, snap, bucket) -> None:
+    def _scatter(self, batch, ids, dists, snap, bucket, t_launch) -> None:
+        """Resolve each request's future; ``t_launch`` is when its launch
+        began packing, so the launch's host time ends where each request's
+        ``latency_ms`` does."""
         t_done = time.monotonic()
-        self.stats.record_batch(batch, bucket)
-        off = 0
-        for r in batch:
-            m = r.points.shape[0]
-            latency_ms = (t_done - r.t_submit) * 1e3
-            self.stats.record_latency(latency_ms)
-            r.future.set_result(AssignResponse(
-                ids=ids[off:off + m].copy(),
-                dists=dists[off:off + m].copy(),
-                model_id=self._entry.model_id,
-                version=snap.version,
-                step=snap.step,
-                latency_ms=latency_ms,
-                batch_rows=bucket,
-                n_coalesced=len(batch)))
-            off += m
+        self.stats.record_batch(batch, bucket, (t_done - t_launch) * 1e3)
+        launch = LAUNCH_ID.get()
+        with TraceAnnotation(spans.SERVE_SCATTER, launch=launch):
+            off = 0
+            for r in batch:
+                m = r.points.shape[0]
+                latency_ms = (t_done - r.t_submit) * 1e3
+                queue_ms = (r.t_dequeue - r.t_submit) * 1e3
+                self.stats.record_latency(latency_ms, queue_ms)
+                r.future.set_result(AssignResponse(
+                    ids=ids[off:off + m].copy(),
+                    dists=dists[off:off + m].copy(),
+                    model_id=self._entry.model_id,
+                    version=snap.version,
+                    step=snap.step,
+                    latency_ms=latency_ms,
+                    batch_rows=bucket,
+                    n_coalesced=len(batch),
+                    queue_ms=queue_ms,
+                    launch=launch))
+                off += m
 
     # -- fault-isolated launch ----------------------------------------------
     def _launch_batch(self, batch: list[_Request]) -> None:
@@ -422,24 +450,35 @@ class Batcher:
         single-request dead end a failure — only a model failing
         *everything* accumulates to the trip threshold.
         """
+        t_launch = time.monotonic()
         snap = self._entry.snapshot()            # ONE snapshot per launch
-        buf, bucket = self._pack(batch, snap.n_features)
-        try:
-            if self._entry.is_demoted(bucket):
-                # Route around the failing primary at the batcher level,
-                # so a wrapped/instrumented primary launch is not touched.
-                ids, dists = self._entry.launch_fallback(
-                    jax.numpy.asarray(buf), snap)
-            else:
-                ids, dists = self._entry.launch(jax.numpy.asarray(buf), snap)
-        except Exception as exc:
-            self._on_launch_fault(batch, buf, snap, bucket, exc)
-            return
-        self._bucket_fail_streak[bucket] = 0
-        self.breaker.record_success()
-        self._scatter(batch, ids, dists, snap, bucket)
+        rows = sum(r.points.shape[0] for r in batch)
+        bucket = self._bucket_for(rows)
+        self._n_launches += 1
+        launch = self._n_launches
+        LAUNCH_ID.set(launch)
+        with TraceAnnotation(spans.SERVE_LAUNCH, launch=launch,
+                             requests=len(batch), rows=rows, bucket=bucket):
+            with TraceAnnotation(spans.SERVE_PACK, launch=launch):
+                buf = self._pack(batch, bucket, snap.n_features)
+            try:
+                if self._entry.is_demoted(bucket):
+                    # Route around the failing primary at the batcher
+                    # level, so a wrapped/instrumented primary launch is
+                    # not touched.
+                    ids, dists = self._entry.launch_fallback(buf, snap)
+                else:
+                    ids, dists = self._entry.launch(buf, snap)
+            except Exception as exc:
+                self._on_launch_fault(batch, buf, snap, bucket, exc,
+                                      t_launch)
+                return
+            self._bucket_fail_streak[bucket] = 0
+            self.breaker.record_success()
+            self._scatter(batch, ids, dists, snap, bucket, t_launch)
 
-    def _on_launch_fault(self, batch, buf, snap, bucket, exc) -> None:
+    def _on_launch_fault(self, batch, buf, snap, bucket, exc,
+                         t_launch) -> None:
         kind = faults.classify(exc)
         self.stats.bump("n_launch_faults")
         self._emit(("launch_fault", self._entry.model_id,
@@ -456,8 +495,7 @@ class Batcher:
             # the device array before failing).
             for _ in range(self._cfg.launch_retries):
                 try:
-                    ids, dists = self._entry.launch_fallback(
-                        jax.numpy.asarray(buf), snap)
+                    ids, dists = self._entry.launch_fallback(buf, snap)
                 except Exception as exc2:  # noqa: BLE001 — classified below
                     exc = exc2
                     self._emit(("launch_fault", self._entry.model_id,
@@ -465,7 +503,7 @@ class Batcher:
                     continue
                 self.stats.bump("n_ref_retries")
                 self.breaker.record_success()
-                self._scatter(batch, ids, dists, snap, bucket)
+                self._scatter(batch, ids, dists, snap, bucket, t_launch)
                 return
         if len(batch) == 1:
             # Fully isolated: this request is implicated; fail it alone.
@@ -486,7 +524,8 @@ class Batcher:
     # -- supervised serve loop ----------------------------------------------
     def _serve_loop(self) -> None:
         while True:
-            batch = self._take_batch()
+            with TraceAnnotation(spans.SERVE_TAKE):
+                batch = self._take_batch()
             if batch is None:
                 return                           # clean shutdown
             if not batch:

@@ -20,6 +20,7 @@ the serving twin of the engine's trace-event vocabulary.
 """
 from __future__ import annotations
 
+import contextvars
 import threading
 import time
 from dataclasses import dataclass, field
@@ -27,9 +28,16 @@ from typing import Any
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import spans
 from repro.kernels import ops
 from repro.kernels import precision as px
+
+# The batcher's id of the launch running on this thread: the ``launch`` arg
+# of the ``repro.serve.dispatch`` / ``fetch`` spans of :meth:`ModelEntry.launch`.
+LAUNCH_ID: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "repro_serve_launch", default=-1)
 
 
 @dataclass(frozen=True)
@@ -111,27 +119,33 @@ class ModelEntry:
                         q, c, impl="ref", precision=self.precision))
             return self._fallback_assign
 
-    def launch(self, q: jax.Array,
-               snapshot: CentroidSnapshot) -> tuple[np.ndarray, np.ndarray]:
+    def launch(self, q, snapshot: CentroidSnapshot
+               ) -> tuple[np.ndarray, np.ndarray]:
         """Run one coalesced assignment launch against ``snapshot``.
 
-        The batcher calls this with the padded request buffer; it is a
-        method (not an inlined jit call) so tests can wrap it to simulate
-        slow kernels without touching the queueing logic.  A bucket the
-        batcher demoted (repeated primary failures) routes straight to the
-        ref fallback.
+        The batcher calls this with the padded request buffer (host or
+        device); it is a method (not an inlined jit call) so tests can wrap
+        it to simulate slow kernels without touching the queueing logic.  A
+        bucket the batcher demoted (repeated primary failures) routes
+        straight to the ref fallback.
         """
         if int(q.shape[0]) in self._demoted_buckets:
             return self.launch_fallback(q, snapshot)
-        ids, d = self._assign(q, snapshot.centroids)
-        return np.asarray(ids), np.asarray(d)
+        return self._run(self._assign, q, snapshot)
 
-    def launch_fallback(self, q: jax.Array,
-                        snapshot: CentroidSnapshot
+    def launch_fallback(self, q, snapshot: CentroidSnapshot
                         ) -> tuple[np.ndarray, np.ndarray]:
         """The ref-path launch: where transient launch faults retry."""
-        ids, d = self._fallback()(q, snapshot.centroids)
-        return np.asarray(ids), np.asarray(d)
+        return self._run(self._fallback(), q, snapshot)
+
+    @staticmethod
+    def _run(assign, q, snapshot: CentroidSnapshot
+             ) -> tuple[np.ndarray, np.ndarray]:
+        launch = LAUNCH_ID.get()
+        with TraceAnnotation(spans.SERVE_DISPATCH, launch=launch):
+            ids, d = assign(jax.numpy.asarray(q), snapshot.centroids)
+        with TraceAnnotation(spans.SERVE_FETCH, launch=launch):
+            return np.asarray(ids), np.asarray(d)
 
     def demote_bucket(self, bucket: int, exc: Exception) -> None:
         """Pin ``bucket`` to the ref path for this entry's lifetime, and

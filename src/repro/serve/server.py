@@ -18,7 +18,7 @@ import time
 from concurrent.futures import Future
 from concurrent.futures import TimeoutError as FutureTimeoutError
 
-from repro.serve.batcher import AssignResponse, Batcher
+from repro.serve.batcher import AssignResponse, Batcher, BatcherStats
 from repro.serve.resilience import CLOSED, DeadlineExceeded
 from repro.serve.config import ServeConfig
 from repro.serve.registry import CentroidSnapshot, ModelEntry, ModelRegistry
@@ -176,6 +176,12 @@ class Server:
         if model_id is not None:
             return one(model_id)
         return {mid: one(mid) for mid in self.models()}
+
+    def batcher_stats(self, model_id: str) -> BatcherStats:
+        """A model's live serving counters, with their per-request
+        (``latencies_ms``, ``queue_ms``) and per-launch (``launch_ms``)
+        series."""
+        return self._batcher(model_id).stats
 
     def recompiles(self, model_id: str) -> int:
         return self.registry.get(model_id).recompiles
