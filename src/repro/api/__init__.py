@@ -221,7 +221,8 @@ def fit(
 
     ``wall_time_s`` on the result covers the whole call, compile included.
     A profiler capture shows the call as the host spans
-    ``repro.fit.dispatch`` (until the jitted call returns) and
+    ``repro.fit.dispatch`` (until the jitted call returns; where the fit
+    runs as one program, its args say how chunk rows are gathered) and
     ``repro.fit.collect`` (reading its result), and its device operations
     under the ``repro.fit.*`` scopes (see :mod:`repro.spans`).
     """
@@ -230,7 +231,7 @@ def fit(
     from repro.kernels import ops as _ops
 
     with TraceAnnotation(spans.FIT_DISPATCH, strategy=method,
-                         n_chunks=cfg.n_chunks):
+                         n_chunks=cfg.n_chunks) as span:
         source = _resolve_source(cfg, data, n_features)
         # Snapshot before any kernel work: the disk cache loads lazily on
         # the first get_blocks lookup, which may happen inside _pretune.
@@ -245,6 +246,7 @@ def fit(
             if program is None:
                 result = fn(cfg, source, key)
             else:
+                span.set_metadata(**program.gather_args())
                 out = program.dispatch()
     if program is None:
         jax.block_until_ready(result.centroids)

@@ -57,6 +57,17 @@ class Program(NamedTuple):
     kwargs: dict
     collect: Callable       # (state, infos) -> FitResult
 
+    def gather_args(self) -> dict:
+        """How the program gathers chunk rows, as args of the
+        ``repro.fit.dispatch`` span: ``gather`` and ``g``, the points a
+        gathered row holds."""
+        from repro.core.bigmeans import LANES, packed_width
+
+        gather = self.kwargs["gather"]
+        n = self.args[0].shape[1]
+        g = LANES // packed_width(n) if gather == "packed" else 1
+        return {"gather": gather, "g": g}
+
     def dispatch(self):
         return self.fn(*self.args, **self.kwargs)
 
@@ -194,7 +205,8 @@ def _plan_sequential(cfg: BigMeansConfig, source: DataSource,
         dict(k=cfg.k, s=cfg.s, n_chunks=cfg.n_chunks,
              max_iters=cfg.max_iters, tol=cfg.tol, candidates=cfg.candidates,
              impl=cfg.impl, with_replacement=cfg.with_replacement,
-             precision=cfg.precision),
+             precision=cfg.precision,
+             gather=incore.gather_for(X, cfg.precision, cfg.s)),
         lambda state, infos: _result_from_state(
             state, infos, cfg, "sequential"))
 
@@ -228,11 +240,14 @@ def _plan_batched(cfg: BigMeansConfig, source: DataSource,
             f"divide batch ({cfg.batch})")
 
     X = _require_array(source, "batched")
+    devices = 1 if mesh is None else topo.devices
     kwargs = dict(
         k=cfg.k, s=cfg.s, batch=cfg.batch, rounds=rounds,
         sync_every=sync_every, max_iters=cfg.max_iters, tol=cfg.tol,
         candidates=cfg.candidates, impl=cfg.impl,
-        with_replacement=cfg.with_replacement, precision=cfg.precision)
+        with_replacement=cfg.with_replacement, precision=cfg.precision,
+        gather=incore.gather_for(X, cfg.precision,
+                                 cfg.batch // devices * cfg.s))
     if mesh is None:
         fn = incore.batched_local
     else:
@@ -278,7 +293,8 @@ def _fit_sharded(cfg: BigMeansConfig, source: DataSource,
         sync_every=sync_every, axes=topo.axes,
         max_iters=cfg.max_iters, tol=cfg.tol, candidates=cfg.candidates,
         impl=cfg.impl, with_replacement=cfg.with_replacement,
-        precision=cfg.precision)
+        precision=cfg.precision,
+        gather=incore.gather_for(X, cfg.precision, cfg.s, shards=workers))
     extras = dict(workers=workers, chunks_per_worker=chunks_per_worker)
     if cfg.ckpt_dir is not None or cfg.time_budget_s is not None:
         # middleware composition (checkpoint/resume, time budget): run the

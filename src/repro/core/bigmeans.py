@@ -28,10 +28,12 @@ trajectories:
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro import spans
 from repro.core import kmeans, kmeanspp
@@ -125,21 +127,91 @@ def chunk_step(
     return new_state, info
 
 
+LANES = 128   # lanes of a TPU vector register: the minor tile of an array
+
+
+def packed_width(n: int) -> int | None:
+    """Lanes a point takes in :func:`pack_rows`'s copy: the next power of
+    two >= ``n`` for a lane-sparse width (n < 128), else None."""
+    if n >= LANES:
+        return None
+    return 1 << (n - 1).bit_length()
+
+
+def packed_rows(m: int, n: int) -> int:
+    """Rows of :func:`pack_rows`' copy of an ``[m, n]`` dataset: ceil(m / g)
+    rounded up to a multiple of 128, g = 128 // w points to a row."""
+    g = LANES // packed_width(n)
+    return -(-m // (g * LANES)) * LANES
+
+
+def pack_rows(X: jax.Array) -> jax.Array:
+    """Point-major packed copy of a lane-sparse dataset ``X [m, n]``.
+
+    ``[R, 128]``, R = :func:`packed_rows`, with g = 128 // w points to a
+    row and w = :func:`packed_width` lanes to a point: point i is in row
+    i % R, lanes (i // R) * w onward, its features padded with zeros (the
+    slots past point m - 1 are zeros).  Each row is lane-dense, so the copy
+    is laid out row-major and one point is one sublane of one tile.  XLA
+    lays a narrow ``[m, n]`` out feature-major instead (each (8, 128) tile
+    holds 8 features of 128 points), where gathering a point reads
+    ceil(n / 8) tiles for n values.
+
+    Built as the transpose of a ``[128, R]`` array that stacks the g
+    blocks of R points of the feature-major dataset, so that no
+    intermediate is lane-sparse.
+    """
+    m, n = X.shape
+    w = packed_width(n)
+    R = packed_rows(m, n)
+    row_major = Layout(major_to_minor=(0, 1))
+    with jax.named_scope(spans.FIT_SAMPLE):
+        xt = X.T
+        blocks = []
+        for j in range(LANES // w):
+            block = xt[:, min(j * R, m):min((j + 1) * R, m)]
+            blocks.append(jnp.pad(
+                block, ((0, w - n), (0, R - block.shape[1]))))   # [w, R]
+        stacked = jnp.concatenate(blocks, axis=0)             # [128, R]
+        stacked = with_layout_constraint(stacked, row_major)
+        return with_layout_constraint(stacked.T, row_major)
+
+
+def _gather_packed(packed: jax.Array, idx: jax.Array, n: int) -> jax.Array:
+    """Rows ``idx`` of the dataset that ``packed`` (:func:`pack_rows`) holds:
+    one packed row per point, then its w-lane group picked by selects,
+    so every value is an exact copy."""
+    w = packed_width(n)
+    R = packed.shape[0]
+    rows = packed.at[idx % R].get(mode="promise_in_bounds")
+    group = (idx // R)[:, None]
+    out = rows[:, :n]
+    for j in range(1, LANES // w):
+        out = jnp.where(group == j, rows[:, j * w:j * w + n], out)
+    return with_layout_constraint(out, Layout(major_to_minor=(0, 1)))
+
+
 def sample_chunk(
-    X: jax.Array, key: jax.Array, s: int, *, with_replacement: bool = True
+    X: jax.Array, key: jax.Array, s: int, *, with_replacement: bool = True,
+    packed: jax.Array | None = None,
 ) -> jax.Array:
     """Uniform random chunk of s rows (the paper's decomposition sampler).
 
     With replacement by default: for s << m the two schemes are statistically
     indistinguishable and the replacement-free path costs an O(m) permutation.
+
+    ``packed``, :func:`pack_rows` of ``X``, gathers the rows from that copy
+    instead of from ``X``: the same indices, the same values.
     """
-    m = X.shape[0]
+    m, n = X.shape
     with jax.named_scope(spans.FIT_SAMPLE):
         if with_replacement:
             idx = jax.random.randint(key, (s,), 0, m)
         else:
             idx = jax.random.choice(key, m, (s,), replace=False)
-        return jnp.take(X, idx, axis=0)
+        if packed is None:
+            return jnp.take(X, idx, axis=0)
+        return _gather_packed(packed, idx, n)
 
 
 def big_means(
@@ -166,7 +238,7 @@ def big_means(
     return incore.sequential(
         X, key, k=k, s=s, n_chunks=n_chunks, max_iters=max_iters, tol=tol,
         candidates=candidates, impl=impl, with_replacement=with_replacement,
-        precision=precision)
+        precision=precision, gather=incore.gather_for(X, precision, s))
 
 
 # ---------------------------------------------------------------------------
@@ -341,17 +413,20 @@ def big_means_batched(
     from repro.engine import incore
 
     assert rounds % sync_every == 0, "sync_every must divide rounds"
+    devices = 1 if mesh is None else mesh.shape[stream_axis]
+    gather = incore.gather_for(X, precision, batch // devices * s)
     if mesh is not None:
         return incore.batched_stream_mesh(
             X, key, mesh=mesh, stream_axis=stream_axis, k=k, s=s,
             batch=batch, rounds=rounds, sync_every=sync_every,
             max_iters=max_iters, tol=tol, candidates=candidates, impl=impl,
             with_replacement=with_replacement, precision=precision,
+            gather=gather,
         )
     return incore.batched_local(
         X, key, k=k, s=s, batch=batch, rounds=rounds, sync_every=sync_every,
         max_iters=max_iters, tol=tol, candidates=candidates, impl=impl,
-        with_replacement=with_replacement, precision=precision,
+        with_replacement=with_replacement, precision=precision, gather=gather,
     )
 
 
@@ -398,8 +473,10 @@ def big_means_sharded(
     """
     from repro.engine import incore
 
+    workers = math.prod(mesh.shape[a] for a in axes)
     return incore.worker_sharded(
         X, key, mesh=mesh, k=k, s=s, chunks_per_worker=chunks_per_worker,
         sync_every=sync_every, axes=axes, max_iters=max_iters, tol=tol,
         candidates=candidates, impl=impl, with_replacement=with_replacement,
-        precision=precision)
+        precision=precision,
+        gather=incore.gather_for(X, precision, s, shards=workers))

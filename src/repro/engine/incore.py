@@ -33,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import spans
 from repro.core.bigmeans import (
+    LANES,
     BigMeansState,
     ChunkInfo,
     _exchange_best,
@@ -41,6 +42,9 @@ from repro.core.bigmeans import (
     chunk_step,
     chunk_step_batched,
     init_state,
+    pack_rows,
+    packed_rows,
+    packed_width,
     reduce_state,
     sample_chunk,
 )
@@ -60,6 +64,49 @@ def _cast_dataset(X, precision):
     return px.cast_storage(X, precision)
 
 
+def _packs(gather, n: int) -> bool:
+    """Whether chunk rows of width ``n`` are gathered from a packed copy:
+    ``gather`` as given, None deciding by the width alone."""
+    if gather is None:
+        return packed_width(n) is not None
+    return gather == "packed"
+
+
+def _dataset(X, precision, gather):
+    """The dataset as the chunk loops sample it: its storage cast, and the
+    point-major packed copy that chunk rows are gathered from (or None).
+    Made once per call, outside the chunk loop."""
+    X = _cast_dataset(X, precision)
+    return X, (pack_rows(X) if _packs(gather, X.shape[1]) else None)
+
+
+def gather_for(X, precision, chunk_rows: int, *, shards: int = 1) -> str:
+    """How the functions below gather chunk rows from ``X``: ``'packed'``,
+    from :func:`repro.core.bigmeans.pack_rows`'s copy, or ``'rows'``, by
+    ``jnp.take`` on the dataset.  The chunks are the same either way.
+
+    Packed where the width is lane-sparse (n < 128) and, where the device
+    reports ``bytes_limit``, the dataset, its packed copy, the intermediate
+    the copy is built through and a gather of ``chunk_rows`` packed rows
+    fit it; ``shards`` is the number of devices the rows are split over.
+    Decided on the host when a fit is planned and passed to them as their
+    static ``gather``; their default, None, decides by the width alone.
+    """
+    m, n = X.shape
+    if packed_width(n) is None:
+        return "rows"
+    devices = X.devices() if isinstance(X, jax.Array) else jax.devices()[:1]
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    if None in limits:
+        return "packed"
+    rows = -(-m // shards)
+    resolved = px.resolve(precision, X.dtype)
+    storage = jnp.float32 if resolved == "int8" else px.storage_dtype(resolved)
+    item = jnp.dtype(storage).itemsize            # as _cast_dataset keeps it
+    need = item * (rows * n + (2 * packed_rows(rows, n) + chunk_rows) * LANES)
+    return "packed" if need <= min(limits) else "rows"
+
+
 _shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
@@ -72,21 +119,25 @@ _shard_map = functools.partial(jax.shard_map, check_vma=False)
     jax.jit,
     static_argnames=(
         "k", "s", "n_chunks", "max_iters", "tol", "candidates", "impl",
-        "with_replacement", "precision",
+        "with_replacement", "precision", "gather",
     ),
 )
 def sequential(
     X, key, *, k, s, n_chunks, max_iters=300, tol=1e-4, candidates=3,
-    impl="auto", with_replacement=True, precision="auto",
+    impl="auto", with_replacement=True, precision="auto", gather=None,
 ):
-    """Sequential Big-means over an in-core dataset.  Returns (state, traces)."""
-    X = _cast_dataset(X, precision)
+    """Sequential Big-means over an in-core dataset.  Returns (state, traces).
+
+    ``gather`` (here and in the functions below) is :func:`gather_for`'s
+    choice: the same chunks either way."""
+    X, packed = _dataset(X, precision, gather)
     state = init_state(k, X.shape[1])
 
     def body(carry, key_i):
         state = carry
         ks, kc = jax.random.split(key_i)
-        chunk = sample_chunk(X, ks, s, with_replacement=with_replacement)
+        chunk = sample_chunk(X, ks, s, with_replacement=with_replacement,
+                             packed=packed)
         state, info = chunk_step(
             chunk, state, kc,
             max_iters=max_iters, tol=tol, candidates=candidates, impl=impl,
@@ -114,16 +165,18 @@ def stream_keys(key, rounds: int, sync_every: int, batch: int):
 
 
 def stream_scan(X, states, keys, *, s, max_iters, tol, candidates, impl,
-                with_replacement, sync_fn, precision="auto"):
+                with_replacement, sync_fn, precision="auto", packed=None):
     """Scan ``rounds`` chunk rounds over per-stream states; ``sync_fn``
-    exchanges incumbents at each sync boundary."""
+    exchanges incumbents at each sync boundary.  ``packed``: the dataset's
+    packed copy to gather from (:func:`sample_chunk`)."""
 
     def body(states, keys_i):                       # keys_i [batch, ...]
         with jax.named_scope(spans.FIT_SAMPLE):
             split = jax.vmap(jax.random.split)(keys_i)  # [batch, 2, ...]
         ks, kc = split[:, 0], split[:, 1]
         chunks = jax.vmap(
-            lambda kk: sample_chunk(X, kk, s, with_replacement=with_replacement)
+            lambda kk: sample_chunk(
+                X, kk, s, with_replacement=with_replacement, packed=packed)
         )(ks)
         return chunk_step_batched(
             chunks, states, kc,
@@ -145,20 +198,20 @@ def stream_scan(X, states, keys, *, s, max_iters, tol, candidates, impl,
     jax.jit,
     static_argnames=(
         "k", "s", "batch", "rounds", "sync_every", "max_iters", "tol",
-        "candidates", "impl", "with_replacement", "precision",
+        "candidates", "impl", "with_replacement", "precision", "gather",
     ),
 )
 def batched_local(
     X, key, *, k, s, batch, rounds, sync_every, max_iters, tol, candidates,
-    impl, with_replacement, precision="auto",
+    impl, with_replacement, precision="auto", gather=None,
 ):
-    X = _cast_dataset(X, precision)
+    X, packed = _dataset(X, precision, gather)
     states = broadcast_state(init_state(k, X.shape[1]), batch)
     keys = stream_keys(key, rounds, sync_every, batch)
     states, infos = stream_scan(
         X, states, keys, s=s, max_iters=max_iters, tol=tol,
         candidates=candidates, impl=impl, with_replacement=with_replacement,
-        sync_fn=_sync_streams, precision=precision,
+        sync_fn=_sync_streams, precision=precision, packed=packed,
     )
     return reduce_state(states), infos
 
@@ -168,16 +221,17 @@ def batched_local(
     static_argnames=(
         "mesh", "stream_axis", "k", "s", "batch", "rounds", "sync_every",
         "max_iters", "tol", "candidates", "impl", "with_replacement",
-        "precision",
+        "precision", "gather",
     ),
 )
 def batched_stream_mesh(
     X, key, *, mesh, stream_axis, k, s, batch, rounds, sync_every,
     max_iters, tol, candidates, impl, with_replacement, precision="auto",
+    gather=None,
 ):
     ndev = mesh.shape[stream_axis]
     assert batch % ndev == 0, "stream mesh axis must divide batch"
-    X = _cast_dataset(X, precision)
+    X, packed = _dataset(X, precision, gather)
     n = X.shape[1]
     keys = stream_keys(key, rounds, sync_every, batch)
 
@@ -198,13 +252,13 @@ def batched_stream_mesh(
                 f_best=jnp.broadcast_to(f_all[g], (bl,)),
             )
 
-    def worker(x_rep, keys_local):          # [outer, sync, batch/D, ...]
+    def worker(x_rep, packed_rep, keys_local):  # keys [outer, sync, batch/D]
         states = broadcast_state(init_state(k, n), keys_local.shape[2])
         states, infos = stream_scan(
             x_rep, states, keys_local, s=s, max_iters=max_iters, tol=tol,
             candidates=candidates, impl=impl,
             with_replacement=with_replacement, sync_fn=sync,
-            precision=precision,
+            precision=precision, packed=packed_rep,
         )
         local = reduce_state(states)
         with jax.named_scope(spans.FIT_KEEP):
@@ -224,13 +278,13 @@ def batched_stream_mesh(
     shard = _shard_map(
         worker,
         mesh=mesh,
-        in_specs=(P(), P(None, None, stream_axis, None)),
+        in_specs=(P(), P(), P(None, None, stream_axis, None)),
         out_specs=(
             BigMeansState(P(), P(), P(), P(), P()),
             ChunkInfo(*([P(stream_axis)] * 4)),
         ),
     )
-    return shard(X, keys)
+    return shard(X, packed, keys)
 
 
 # ---------------------------------------------------------------------------
@@ -241,7 +295,7 @@ def batched_stream_mesh(
 def worker_sharded(
     X, key, *, mesh, k, s, chunks_per_worker, sync_every=1, axes=("data",),
     max_iters=300, tol=1e-4, candidates=3, impl="auto",
-    with_replacement=True, precision="auto",
+    with_replacement=True, precision="auto", gather=None,
 ):
     """Multi-worker Big-means: X row-sharded over ``axes``; per-worker chunk
     streams with periodic incumbent exchange.
@@ -264,12 +318,15 @@ def worker_sharded(
                 widx = widx * mesh.shape[a] + jax.lax.axis_index(a)
         key = jax.random.fold_in(key, widx)
         state = init_state(k, x_local.shape[1])
+        packed = (pack_rows(x_local) if _packs(gather, x_local.shape[1])
+                  else None)
 
         def round_body(state, key_r):
             def body(state, key_i):
                 ks, kc = jax.random.split(key_i)
                 chunk = sample_chunk(
-                    x_local, ks, s, with_replacement=with_replacement
+                    x_local, ks, s, with_replacement=with_replacement,
+                    packed=packed,
                 )
                 return chunk_step(
                     chunk, state, kc,
@@ -318,7 +375,7 @@ def worker_sharded(
     ),
 )
 def _sharded_segment(
-    X, key, r, states, *, mesh, axes, k, s, n_rounds, sync_every,
+    X, packed, key, r, states, *, mesh, axes, k, s, n_rounds, sync_every,
     max_iters, tol, candidates, impl, with_replacement, precision,
 ):
     """Window ``r`` of the worker-sharded run: ``sync_every`` chunks per
@@ -329,10 +386,13 @@ def _sharded_segment(
     worker folds its index into the base key, splits ``n_rounds`` round
     keys, and consumes round ``r``'s — so an uninterrupted sequence of
     segments replays the one-shot driver's trajectory exactly.
+
+    ``packed`` is each worker's :func:`pack_rows` of its shard, row-sharded
+    like ``X`` (:func:`_pack_shards`), or None.
     """
     axis = axes if len(axes) > 1 else axes[0]
 
-    def worker(x_local, key, r, state_stack):
+    def worker(x_local, packed_local, key, r, state_stack):
         widx = jax.lax.axis_index(axes[0])
         if len(axes) > 1:
             for a in axes[1:]:
@@ -344,7 +404,8 @@ def _sharded_segment(
         def body(state, key_i):
             ks, kc = jax.random.split(key_i)
             chunk = sample_chunk(
-                x_local, ks, s, with_replacement=with_replacement)
+                x_local, ks, s, with_replacement=with_replacement,
+                packed=packed_local)
             return chunk_step(
                 chunk, state, kc,
                 max_iters=max_iters, tol=tol, candidates=candidates,
@@ -360,21 +421,29 @@ def _sharded_segment(
     shard = _shard_map(
         worker,
         mesh=mesh,
-        in_specs=(P(axes), P(), P(),
+        in_specs=(P(axes), P(axes), P(), P(),
                   BigMeansState(*([P(axes)] * 5))),
         out_specs=(
             BigMeansState(*([P(axes)] * 5)),
             ChunkInfo(*([P(axes[0])] * 4)),
         ),
     )
-    return shard(X, key, r, states)
+    return shard(X, packed, key, r, states)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "axes"))
+def _pack_shards(X, *, mesh, axes):
+    """:func:`pack_rows` of each worker's row shard of ``X``, row-sharded
+    the same way."""
+    return _shard_map(pack_rows, mesh=mesh, in_specs=P(axes),
+                      out_specs=P(axes))(X)
 
 
 def worker_sharded_rounds(
     X, key, *, mesh, k, s, chunks_per_worker, sync_every=1, axes=("data",),
     max_iters=300, tol=1e-4, candidates=3, impl="auto",
-    with_replacement=True, precision="auto", cfg=None, middlewares=None,
-    resume=True,
+    with_replacement=True, precision="auto", gather=None, cfg=None,
+    middlewares=None, resume=True,
 ):
     """Worker-sharded Big-means with the accept loop on the host.
 
@@ -396,6 +465,8 @@ def worker_sharded_rounds(
         W *= int(mesh.shape[a])
     xd = _cast_dataset(X, precision)
     n = X.shape[1]
+    packed = (_pack_shards(xd, mesh=mesh, axes=tuple(axes))
+              if _packs(gather, n) else None)
 
     stack = mw.MiddlewareStack(middlewares or [])
     states = jax.tree.map(
@@ -414,7 +485,7 @@ def worker_sharded_rounds(
     window_infos = []
     for r in range(start_round, n_rounds):
         states, infos = _sharded_segment(
-            xd, key, jnp.int32(r), states,
+            xd, packed, key, jnp.int32(r), states,
             mesh=mesh, axes=tuple(axes), k=k, s=s, n_rounds=n_rounds,
             sync_every=sync_every, max_iters=max_iters, tol=tol,
             candidates=candidates, impl=impl,

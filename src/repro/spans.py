@@ -10,7 +10,9 @@ under two microseconds and a scope nothing at run time.
 Host spans:
 
 * ``repro.fit.dispatch`` — ``fit()`` entry until its jitted call returns
-  (args ``strategy``, ``n_chunks``);
+  (args ``strategy``, ``n_chunks``; and where the fit runs as one jitted
+  program, ``gather``: ``packed`` or ``rows``, and ``g``, the points one
+  gathered row holds);
 * ``repro.fit.collect`` — reading the fit's result to the host;
 * ``repro.serve.take`` — the batcher waiting and lingering for requests;
 * ``repro.serve.launch`` — one launch, pack to scatter (args ``launch``,
@@ -21,7 +23,8 @@ Host spans:
   (resolving each request's future), which carry its ``launch``.
 
 Device scopes: ``repro.fit.sample`` (drawing a chunk's rows and gathering
-them), ``repro.fit.seed`` (K-means++ re-seeding of degenerate slots),
+them, and the dataset's packed copy they are gathered from),
+``repro.fit.seed`` (K-means++ re-seeding of degenerate slots),
 ``repro.fit.lloyd`` (the Lloyd search: lane pad, kernel, epilogue) and
 ``repro.fit.keep`` (keep-the-best and the incumbent exchange).
 """

@@ -52,6 +52,22 @@ out["batched_mesh_matches"] = bool(
     and np.allclose(np.asarray(stb.centroids), np.asarray(stm.centroids),
                     rtol=1e-4, atol=1e-4)
     and int(stb.n_accepted) == int(stm.n_accepted))
+
+# chunk rows gathered from the packed copy or by rows: the same fits
+from repro.engine import incore
+X28 = gmm_dataset(GMMSpec(m=16000, n=28, components=5, seed=4))
+def same(a, b):
+    return all(np.array_equal(np.asarray(u), np.asarray(v))
+               for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+stream = [incore.batched_stream_mesh(
+    X28, key, mesh=smesh, stream_axis="streams", k=5, s=800, batch=8,
+    rounds=2, sync_every=1, max_iters=300, tol=1e-4, candidates=3,
+    impl="ref", with_replacement=True, gather=g) for g in ("rows", "packed")]
+out["stream_mesh_gathers_match"] = same(*stream)
+workers = [incore.worker_sharded(
+    X28, key, mesh=mesh, k=5, s=800, chunks_per_worker=4, sync_every=2,
+    impl="ref", gather=g) for g in ("rows", "packed")]
+out["worker_mesh_gathers_match"] = same(*workers)
 print("RESULT " + json.dumps(out))
 """
 
@@ -79,3 +95,11 @@ def test_sharded_progress(result):
 
 def test_batched_stream_mesh_matches_local(result):
     assert result["batched_mesh_matches"]
+
+
+def test_a_stream_mesh_fit_is_the_same_from_either_gather(result):
+    assert result["stream_mesh_gathers_match"]
+
+
+def test_a_worker_mesh_fit_is_the_same_from_either_gather(result):
+    assert result["worker_mesh_gathers_match"]

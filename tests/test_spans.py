@@ -16,6 +16,7 @@ import pytest
 
 from repro import spans
 from repro.api import BigMeansConfig, ServeConfig, fit, lower_fit, serve
+from repro.core.bigmeans import LANES
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
 
@@ -34,11 +35,14 @@ def _instructions(hlo_text: str, opcode: str) -> dict:
     return out
 
 
-def _check_scope_map(hlo_text: str, chunk_elements: int) -> dict:
+def _check_scope_map(hlo_text: str, chunk_rows: int) -> dict:
+    """Every scope is mapped, and the gather of a whole chunk batch's rows
+    (from the packed copy of the 8-wide dataset: 128 lanes a row) is
+    sampling's."""
     scopes = spans.op_scopes(hlo_text)
     assert set(spans.SCOPES) <= set(scopes.values())
     chunk = [n for n, size in _instructions(hlo_text, "gather").items()
-             if size == chunk_elements]
+             if size == chunk_rows * LANES]
     assert chunk, "no gather of a whole chunk batch in the program"
     assert {scopes[n] for n in chunk} == {spans.FIT_SAMPLE}
     return scopes
@@ -74,7 +78,7 @@ def test_lower_fit_is_the_program_fit_runs_and_maps_every_scope():
                          impl="ref", max_iters=10)
     key = jax.random.PRNGKey(3)
     compiled = lower_fit(X, cfg, method="batched", key=key).compile()
-    _check_scope_map(compiled.as_text(), chunk_elements=4 * 500 * 8)
+    _check_scope_map(compiled.as_text(), chunk_rows=4 * 500)
     state, _ = compiled(X, key)
     assert float(state.f_best) == fit(X, cfg, method="batched",
                                       key=key).objective
@@ -115,7 +119,7 @@ def test_lower_fit_maps_every_scope_on_a_four_device_stream_mesh():
             if ln.startswith("RESULT ")][-1]
     text = json.loads(line[len("RESULT "):])["text"]
     # each device gathers its 2 of the 8 streams' chunks
-    scopes = _check_scope_map(text, chunk_elements=2 * 500 * 8)
+    scopes = _check_scope_map(text, chunk_rows=2 * 500)
     exchange = [*_instructions(text, "all-gather"),
                 *_instructions(text, "all-reduce")]
     assert exchange and {scopes[n] for n in exchange} == {spans.FIT_KEEP}
@@ -180,15 +184,31 @@ def test_queue_wait_lies_within_latency_and_each_launch_is_timed():
     assert stats["queue_p99_ms"] <= stats["p99_ms"]
 
 
-def test_a_profile_of_fit_holds_its_dispatch_and_collect_spans(tmp_path):
-    X = jax.random.normal(jax.random.PRNGKey(0), (2000, 4))
+def _dispatch_and_collect(tmp_path, X, method):
     cfg = BigMeansConfig(k=3, s=200, n_chunks=4, batch=2, impl="ref",
                          max_iters=5)
-    fit(X, cfg, method="batched")                       # compile outside
+    fit(X, cfg, method=method)                          # compile outside
     with jax.profiler.trace(str(tmp_path)):
-        fit(X, cfg, method="batched")
+        fit(X, cfg, method=method)
     events = _host_events(sorted(tmp_path.rglob("*.xplane.pb"))[-1])
     (dispatch,) = [e for e in events if e[0] == spans.FIT_DISPATCH]
     (collect,) = [e for e in events if e[0] == spans.FIT_COLLECT]
-    assert dispatch[3] == {"strategy": "batched", "n_chunks": 4}
     assert dispatch[2] <= collect[1]                    # disjoint, in order
+    return dispatch[3]
+
+
+def test_a_profile_of_fit_holds_its_dispatch_and_collect_spans(tmp_path):
+    X = jax.random.normal(jax.random.PRNGKey(0), (2000, 4))
+    # 4 features take 4 lanes of a packed row: 32 points to a row
+    assert _dispatch_and_collect(tmp_path, X, "batched") == {
+        "strategy": "batched", "n_chunks": 4, "gather": "packed", "g": 32}
+
+
+@pytest.mark.parametrize("n, gather, g", [(28, "packed", 4),
+                                          (100, "packed", 1),
+                                          (130, "rows", 1)])
+def test_the_dispatch_span_says_how_chunk_rows_are_gathered(tmp_path, n,
+                                                             gather, g):
+    X = jax.random.normal(jax.random.PRNGKey(1), (1000, n))
+    args = _dispatch_and_collect(tmp_path, X, "sequential")
+    assert (args["gather"], args["g"]) == (gather, g)

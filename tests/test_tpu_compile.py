@@ -142,6 +142,37 @@ def test_update_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+M_HEPMASS = 10_500_000
+GIB = 2 ** 30
+
+
+def _fit_kwargs(precision):
+    return dict(k=K, s=S, batch=8, rounds=2, sync_every=2, max_iters=300,
+                tol=1e-4, candidates=3, impl="pallas", with_replacement=True,
+                precision=precision, gather="packed")
+
+
+def _chunk_gathers(text, streams=8):
+    """``slice_sizes`` of each gather of a whole chunk batch's rows."""
+    return {line.split("slice_sizes={")[1].split("}")[0]
+            for line in text.splitlines()
+            if " gather(" in line and f"[{streams},{S}," in line}
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_packed_gather_fit_compiles_at_hepmass_size(one_chip, precision):
+    """The in-core fit at HEPMASS's 10.5M x 28 gathers each chunk row as one
+    128-lane row of the packed copy, not as a point of the feature-major
+    dataset, and the copy stays within a few GiB of the 16 GiB chip."""
+    X = jax.ShapeDtypeStruct((M_HEPMASS, N), jnp.float32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = incore.batched_local.lower(
+        X, key, **_fit_kwargs(precision)).compile()
+    assert _chunk_gathers(compiled.as_text()) == {"1,128"}
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6 * GIB
+
+
 def _mesh(topo, axis):
     return Mesh(np.array(topo.devices[:4]), (axis,))
 
@@ -177,3 +208,18 @@ def test_worker_mesh_sharded_compiles_on_four_chips(topo):
             x, k, mesh=mesh, k=K, s=S, chunks_per_worker=2, sync_every=2,
             impl="pallas", precision="f32"), X, key)
     assert "tpu_custom_call" in text
+
+
+def test_packed_gather_stream_mesh_fit_compiles_on_four_chips(topo):
+    """Each chip of the stream mesh packs its replica of the dataset and
+    gathers its streams' chunk rows from that copy."""
+    mesh = _mesh(topo, "streams")
+    rep = NamedSharding(mesh, P())
+    X = jax.ShapeDtypeStruct((M_HEPMASS, N), jnp.float32, sharding=rep)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    compiled = incore.batched_stream_mesh.lower(
+        X, key, mesh=mesh, stream_axis="streams",
+        **_fit_kwargs("f32")).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _chunk_gathers(text, streams=2) == {"1,128"}
