@@ -222,7 +222,8 @@ def fit(
     ``wall_time_s`` on the result covers the whole call, compile included.
     A profiler capture shows the call as the host spans
     ``repro.fit.dispatch`` (until the jitted call returns; where the fit
-    runs as one program, its args say how chunk rows are gathered) and
+    runs as one program, its args say how chunk rows are gathered and
+    which Lloyd path runs) and
     ``repro.fit.collect`` (reading its result), and its device operations
     under the ``repro.fit.*`` scopes (see :mod:`repro.spans`).
     """
@@ -246,7 +247,7 @@ def fit(
             if program is None:
                 result = fn(cfg, source, key)
             else:
-                span.set_metadata(**program.gather_args())
+                span.set_metadata(**program.dispatch_args())
                 out = program.dispatch()
     if program is None:
         jax.block_until_ready(result.centroids)

@@ -28,6 +28,8 @@ class FitResult:
     * ``n_chunks`` — chunks processed (0 for full-data baselines).
     * ``n_accepted`` — incumbent improvements (Big-means keep-the-best).
     * ``n_iterations`` — total Lloyd iterations.
+    * ``seed_steps`` — D^2 steps the K-means++ seeding loops ran, summed
+      over chunks: k for each chunk whose step seeded (0 for baselines).
     * ``n_dist_evals`` — the paper's analytic n_d counter (NaN where the
       algorithm does not track it).
     * ``trace`` — list of trace entries; Big-means strategies log
@@ -49,6 +51,7 @@ class FitResult:
     n_chunks: int = 0
     n_accepted: int = 0
     n_iterations: int = 0
+    seed_steps: int = 0
     n_dist_evals: float = math.nan
     wall_time_s: float = 0.0
     trace: list = dataclasses.field(default_factory=list)
@@ -86,6 +89,7 @@ class FitResult:
             "n_chunks": int(self.n_chunks),
             "n_accepted": int(self.n_accepted),
             "n_iterations": int(self.n_iterations),
+            "seed_steps": int(self.seed_steps),
             "n_dist_evals": None if math.isnan(nd) else float(nd),
             "wall_time_s": float(self.wall_time_s),
             "fit": self.extras.get("fit"),

@@ -57,16 +57,28 @@ class Program(NamedTuple):
     kwargs: dict
     collect: Callable       # (state, infos) -> FitResult
 
-    def gather_args(self) -> dict:
-        """How the program gathers chunk rows, as args of the
-        ``repro.fit.dispatch`` span: ``gather`` and ``g``, the points a
-        gathered row holds."""
+    def dispatch_args(self) -> dict:
+        """How the program runs, as args of the ``repro.fit.dispatch``
+        span: ``gather`` and ``g``, the points a gathered row holds, and
+        ``lloyd``, ``fused`` or ``two_pass``, with ``k_tiles``, the lane
+        tiles of k the fused kernel walks (0 on the two-pass path)."""
         from repro.core.bigmeans import LANES, packed_width
+        from repro.kernels import ops
+        from repro.kernels import precision as px
 
-        gather = self.kwargs["gather"]
-        n = self.args[0].shape[1]
+        kw = self.kwargs
+        gather = kw["gather"]
+        X = self.args[0]
+        n = X.shape[1]
         g = LANES // packed_width(n) if gather == "packed" else 1
-        return {"gather": gather, "g": g}
+        devices = 1 if kw.get("mesh") is None \
+            else kw["mesh"].shape[kw["stream_axis"]]
+        batch = kw.get("batch", 1) // devices
+        lloyd, k_tiles = ops.lloyd_path(
+            "fused_batched" if "batch" in kw else "fused", impl=kw["impl"],
+            b=batch, m=kw["s"], k=kw["k"], n=n,
+            precision=px.resolve(kw["precision"], X.dtype))
+        return {"gather": gather, "g": g, "lloyd": lloyd, "k_tiles": k_tiles}
 
     def dispatch(self):
         return self.fn(*self.args, **self.kwargs)
@@ -158,6 +170,7 @@ def _trace_from_infos(infos) -> list:
 
 
 def _result_from_state(state, infos, cfg, strategy, **extras) -> FitResult:
+    seed_steps = infos.seed_steps
     return FitResult(
         centroids=state.centroids,
         objective=float(state.f_best),
@@ -166,6 +179,8 @@ def _result_from_state(state, infos, cfg, strategy, **extras) -> FitResult:
         n_chunks=int(np.asarray(infos.f_new).size),
         n_accepted=int(state.n_accepted),
         n_iterations=int(np.sum(np.asarray(infos.lloyd_iters))),
+        seed_steps=0 if seed_steps is None
+        else int(np.sum(np.asarray(seed_steps))),
         n_dist_evals=float(state.n_dist_evals),
         trace=_trace_from_infos(infos),
         checkpoint_dir=None,
@@ -382,6 +397,7 @@ def _fit_streaming(cfg: BigMeansConfig, source: DataSource,
         n_chunks=metrics.chunks_done,
         n_accepted=metrics.accepted,
         n_iterations=metrics.lloyd_iters,
+        seed_steps=metrics.seed_steps,
         n_dist_evals=float(state.n_dist_evals),
         wall_time_s=metrics.wall_time_s,
         trace=list(metrics.trace),

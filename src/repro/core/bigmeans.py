@@ -52,6 +52,10 @@ class ChunkInfo(NamedTuple):
     accepted: jax.Array
     lloyd_iters: jax.Array
     n_degenerate: jax.Array
+    # D^2 steps the K-means++ loop ran for the chunk: k where the chunk step
+    # seeded (any slot degenerate; in a batched step, in any stream), else 0.
+    # None only where a ChunkInfo is built without it.
+    seed_steps: jax.Array | None = None
 
 
 def init_state(k: int, n: int) -> BigMeansState:
@@ -88,8 +92,9 @@ def chunk_step(
     # loop is skipped at runtime in that (steady-state) case — on CPU the
     # D^2 probes are the dominant per-chunk cost.
     with jax.named_scope(spans.FIT_SEED):
+        seeds = jnp.any(state.degenerate)
         c_init = jax.lax.cond(
-            jnp.any(state.degenerate),
+            seeds,
             lambda: kmeanspp.seed(
                 points, key, k,
                 init=state.centroids,
@@ -123,6 +128,7 @@ def chunk_step(
             accepted=accepted,
             lloyd_iters=res.iterations,
             n_degenerate=jnp.sum(res.degenerate),
+            seed_steps=jnp.where(seeds, jnp.int32(k), jnp.int32(0)),
         )
     return new_state, info
 
@@ -327,8 +333,9 @@ def chunk_step_batched(
     # Same runtime skip as `chunk_step`: when no stream has a degenerate
     # slot (the steady state) the batched probe loop is bypassed entirely.
     with jax.named_scope(spans.FIT_SEED):
+        seeds = jnp.any(states.degenerate)
         c_init = jax.lax.cond(
-            jnp.any(states.degenerate),
+            seeds,
             lambda: kmeanspp.seed_batched(
                 points, keys, k,
                 init=states.centroids,
@@ -364,6 +371,9 @@ def chunk_step_batched(
             accepted=accepted,
             lloyd_iters=res.iterations,
             n_degenerate=jnp.sum(res.degenerate, axis=1),
+            seed_steps=jnp.broadcast_to(
+                jnp.where(seeds, jnp.int32(k), jnp.int32(0)),
+                res.iterations.shape),
         )
     return new_states, info
 

@@ -417,7 +417,8 @@ class HostExchanger:
         payload = self._payload(state, state.f_best, size)
         payload["health"] = _json_arr(dict(
             health_dict(ctx.metrics), rank=self.rank,
-            lloyd_iters=ctx.metrics.lloyd_iters))
+            lloyd_iters=ctx.metrics.lloyd_iters,
+            seed_steps=ctx.metrics.seed_steps))
         gathered = self._gather(ctx, "final", payload, "final")
         winner = self._winner(gathered)
         acc, nd = self._merge_counters(gathered)
@@ -439,6 +440,8 @@ class HostExchanger:
         ctx.metrics.accepted = acc
         ctx.metrics.lloyd_iters = sum(
             h.get("lloyd_iters", 0) for h in per_rank)
+        ctx.metrics.seed_steps = sum(
+            h.get("seed_steps", 0) for h in per_rank)
         ctx.metrics.host = {
             "rank": self.rank,
             "processes": self.R,

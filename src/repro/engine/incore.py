@@ -281,7 +281,7 @@ def batched_stream_mesh(
         in_specs=(P(), P(), P(None, None, stream_axis, None)),
         out_specs=(
             BigMeansState(P(), P(), P(), P(), P()),
-            ChunkInfo(*([P(stream_axis)] * 4)),
+            ChunkInfo(*([P(stream_axis)] * len(ChunkInfo._fields))),
         ),
     )
     return shard(X, packed, keys)
@@ -354,7 +354,7 @@ def worker_sharded(
         in_specs=(P(axes), P()),
         out_specs=(
             BigMeansState(P(), P(), P(), P(), P()),
-            ChunkInfo(*([P(axes[0])] * 4)),
+            ChunkInfo(*([P(axes[0])] * len(ChunkInfo._fields))),
         ),
     )
     xd = _cast_dataset(X, precision)
@@ -425,7 +425,7 @@ def _sharded_segment(
                   BigMeansState(*([P(axes)] * 5))),
         out_specs=(
             BigMeansState(*([P(axes)] * 5)),
-            ChunkInfo(*([P(axes[0])] * 4)),
+            ChunkInfo(*([P(axes[0])] * len(ChunkInfo._fields))),
         ),
     )
     return shard(X, packed, key, r, states)
@@ -529,4 +529,5 @@ def _zero_infos(k):
         accepted=jnp.zeros((1,), bool),
         lloyd_iters=jnp.zeros((1,), jnp.int32),
         n_degenerate=jnp.zeros((1,), jnp.int32),
+        seed_steps=jnp.zeros((1,), jnp.int32),
     )

@@ -88,6 +88,7 @@ class RunnerMetrics:
     chunks_quarantined: int = 0
     accepted: int = 0
     lloyd_iters: int = 0
+    seed_steps: int = 0
     wall_time_s: float = 0.0
     f_best: float = float("inf")
     trace: list = dataclasses.field(default_factory=list)
@@ -482,6 +483,7 @@ def _consume_info(ctx, info):
     m = ctx.metrics
     m.accepted += int(np.sum(np.asarray(info.accepted)))
     m.lloyd_iters += int(np.sum(np.asarray(info.lloyd_iters)))
+    m.seed_steps += int(np.sum(np.asarray(info.seed_steps)))
 
 
 def _run_fold(source, state, ctx, stack, kernel, scheduler, sync, host=None):

@@ -191,8 +191,7 @@ def candidates(kind: str, *, b: int, m: int, k: int, n: int,
                             "pipeline": pipe}
                     if cand in out:
                         continue
-                    k_pad, n_pad, _, _ = fused._batched_tiles(k, n, bk, bn)
-                    if k_pad * n_pad > fused._MAX_KN_ELEMS:
+                    if not fused.fits_tiling(k, n, bk, bn):
                         continue
                     out.append(cand)
     elif kind == "fused_batched":
@@ -201,13 +200,12 @@ def candidates(kind: str, *, b: int, m: int, k: int, n: int,
         _, _, bk0, bn0 = fused._batched_tiles(k, n)
         out.append({"block_m": 256, "block_k": bk0, "block_n": bn0})
         for bm in (128, 256, 512):
-            for bk in (128, 256):
+            for bk in (bk0, 128, 256):
                 for bn in (256, 512):
                     cand = {"block_m": bm, "block_k": bk, "block_n": bn}
                     if cand in out:
                         continue
-                    k_pad, n_pad, _, _ = fused._batched_tiles(k, n, bk, bn)
-                    if k_pad * n_pad > fused._MAX_KN_ELEMS:
+                    if not fused.fits_tiling(k, n, bk, bn):
                         continue
                     out.append(cand)
     elif kind == "assign":

@@ -15,10 +15,22 @@ halving the dominant HBM traffic of Big-means' inner loop.
 k and n are tiled *inside* the kernel: k is processed in ``block_k`` lane
 tiles with a running (min, argmin) pair carried across tiles — the full
 s x k distance block is never materialized — and the distance matmul
-contracts n in ``block_n`` tiles.  That lifts the historical single-chunk
-wall (k <= 128, n <= 1024) to the VMEM-working-set envelope :func:`fits`
-(k <= 1024, n <= 4096, k_pad * n_pad <= 1M elements) for the single and
-batched variants alike.
+contracts n in ``block_n`` tiles.  Both tile loops are unrolled at trace
+time; the default lane tile grows with k (:func:`_default_block_k`) so a
+kernel never walks more than :data:`UNROLL_K_TILES` of them: 128 lanes up
+to k = 1024, 512 at k = 4096.  The envelope :func:`fits` is what stays
+resident in VMEM for the single and batched variants alike: the centroid
+block and the sums block, ``k_pad x n_pad`` four-byte words each,
+double-buffered, at most :data:`RESIDENT_BYTES` (16 MiB, so k_pad * n_pad
+<= 1M: k = 4096 at n = 256, k = 8192 at n = 128), with n <= 4096.
+
+The update contracts the one-hot assignment with the point tile.  Past
+k = 1024, where the lane tile widens, the kernel is bound by the MXU and
+the update is half its contractions: a 0/1 matrix is exact in bf16, so
+under ``'f32'`` it is three single-pass bf16 products with the point
+tile's three bf16 parts (:func:`~repro.kernels.precision.onehot_dot`)
+rather than the six passes of ``Precision.HIGHEST``.  Every k <= 1024
+keeps the policy's dot and the program it compiled to before.
 
 Mixed precision (``precision='bf16'``): the chunk and centroids are stored
 and streamed bf16 — halving the remaining HBM bytes again — and both MXU
@@ -70,16 +82,19 @@ _BIG = 1e30
 
 # VMEM-working-set envelope: k and n are tiled inside the kernel, so the
 # wall is the resident c + sums blocks, not the lane width.
-MAX_K = 1024
 MAX_N = 4096
-_MAX_KN_ELEMS = 1 << 20        # k_pad * n_pad <= 1M f32 (4 MB per block)
+RESIDENT_BYTES = 16 * 2**20    # c + sums blocks, double-buffered, 4 B words
+
+# The most lane tiles of k a kernel walks at its default tiling: past
+# 8 x 128 centroids the default lane tile widens instead.
+UNROLL_K_TILES = 8
 
 # Historical single-chunk envelope (pre-tiling), kept for tests/docs: shapes
 # beyond it used to fall back to the two-pass ref path.
 LEGACY_MAX_K = 128
 LEGACY_MAX_N = 1024
 
-_BLOCK_K = 128                 # lane tile for the running argmin
+_BLOCK_K = 128                 # narrowest lane tile for the running argmin
 _BLOCK_N = 512                 # contraction tile for the distance matmul
 
 PIPELINES = ("blocks", "dma")
@@ -100,10 +115,17 @@ def _pad_to(a, size, axis, value=0.0):
     return jnp.pad(a, widths, constant_values=value)
 
 
+def _default_block_k(k: int) -> int:
+    """The narrowest multiple of 128 lanes that covers k in at most
+    UNROLL_K_TILES tiles (128 for every k <= 1024)."""
+    groups = -(-k // _BLOCK_K)
+    return _BLOCK_K * -(-groups // UNROLL_K_TILES)
+
+
 def _batched_tiles(k: int, n: int, block_k: int | None = None,
                    block_n: int | None = None) -> tuple[int, int, int, int]:
     """(k_pad, n_pad, block_k, block_n) used by the fused kernels."""
-    block_k = _BLOCK_K if block_k is None else block_k
+    block_k = _default_block_k(k) if block_k is None else block_k
     k_pad = -(-k // block_k) * block_k
     n_pad = -(-n // 128) * 128
     if block_n is None:
@@ -119,35 +141,80 @@ def _lane_tiles(row, k_pad: int, block_k: int, value=0.0):
     return row.reshape(row.shape[:-1] + (k_pad // block_k, 1, block_k))
 
 
+def resident_bytes(k: int, n: int, block_k: int | None = None,
+                   block_n: int | None = None) -> int:
+    """VMEM bytes of the blocks a fused kernel keeps resident at this tiling:
+    the ``k_pad x n_pad`` centroid and sums blocks (f32, or int8 codes and
+    int32 sums: at most four bytes a word), each double-buffered."""
+    k_pad, n_pad, _, _ = _batched_tiles(k, n, block_k, block_n)
+    return 2 * 2 * 4 * k_pad * n_pad
+
+
 def fits(k: int, n: int) -> bool:
-    k_pad, n_pad, _, _ = _batched_tiles(k, n)
-    return k <= MAX_K and n <= MAX_N and k_pad * n_pad <= _MAX_KN_ELEMS
+    """Whether ``(k, n)`` runs in the fused kernels at the default tiling:
+    ``n <= MAX_N`` and :func:`resident_bytes` within RESIDENT_BYTES."""
+    return n <= MAX_N and resident_bytes(k, n) <= RESIDENT_BYTES
+
+
+def fits_tiling(k: int, n: int, block_k: int, block_n: int) -> bool:
+    """Whether a tiling is one the kernels take at ``(k, n)``: the resident
+    blocks within RESIDENT_BYTES, at most UNROLL_K_TILES lane tiles of k."""
+    return resident_bytes(k, n, block_k, block_n) <= RESIDENT_BYTES \
+        and k_tiles(k, block_k) <= UNROLL_K_TILES
+
+
+def max_k(n: int) -> int:
+    """The largest k that :func:`fits` at width ``n`` (0 past MAX_N)."""
+    if n > MAX_N:
+        return 0
+    return RESIDENT_BYTES // resident_bytes(_BLOCK_K, n) * _BLOCK_K
+
+
+def k_tiles(k: int, block_k: int | None = None) -> int:
+    """Lane tiles the kernels walk for ``k`` centroids."""
+    block_k = _default_block_k(k) if block_k is None else block_k
+    return -(-k // block_k)
 
 
 # Single and batched kernels share one envelope since the k/n tiling moved
 # into both bodies.
 fits_batched = fits
 
-MAX_K_BATCHED = MAX_K
-MAX_N_BATCHED = MAX_N
+
+def _unpack_fused_refs(args, precision: str):
+    """(x, c, csq, t, scale, sums, counts, obj, rest) from positional refs."""
+    if precision == "int8":
+        x_ref, c_ref, csq_ref, t_ref, scale_ref = args[:5]
+        rest = args[5:]
+    else:
+        x_ref, c_ref, csq_ref = args[:3]
+        t_ref = scale_ref = None
+        rest = args[3:]
+    sums_ref, counts_ref, obj_ref = rest[:3]
+    return x_ref, c_ref, csq_ref, t_ref, scale_ref, sums_ref, counts_ref, \
+        obj_ref, rest[3:]
 
 
-def _tile_argmin(x, c, csq, *, block_k: int, block_n: int, precision: str,
-                 t=None, scale=None):
-    """Running (min, argmin) across k lane tiles for one resident point tile.
+def _accumulate(i, x, c, csq, t, scale, sums_ref, counts_ref, obj_ref, *,
+                lead: tuple, m: int, block_m: int, block_k: int,
+                block_n: int, precision: str):
+    """Process one resident point tile and accumulate into the output refs.
 
-    ``x`` [bm, n_pad], ``c`` [k_pad, n_pad], ``csq`` [nk, 1, block_k]; under
-    int8 ``t`` [nk, 1, block_k] are the per-row centroid scales and ``scale``
-    [1, n_pad] the per-feature chunk scales.  The per-centroid rows come
-    tile-major (see :func:`_lane_tiles`) so tile ``j`` is a leading-axis
-    index, never a lane slice at an offset: Mosaic cannot broadcast a
-    ``(1, block_k)`` slice taken at lane offset ``j * block_k > 0``.
-    Returns (bidx int32 [bm], best f32 [bm], xsq f32 [bm]).  Both tile
-    loops are unrolled at trace time.
+    Scores ``||c||^2 - 2 x.c`` per k lane tile with a running (min,
+    argmin) pair carried across tiles, then the one-hot update of each
+    tile's sums and counts, then the tile's objective; both tile loops are
+    unrolled at trace time.  ``x`` [bm, n_pad] is the point tile and ``i``
+    its index (python int or tracer); ``c`` [k_pad, n_pad] the centroids.
+    ``csq`` and, under int8, ``t`` (the per-row centroid scales) come
+    tile-major ``[nk, 1, block_k]`` (see :func:`_lane_tiles`) so tile
+    ``j`` is a leading-axis index, never a lane slice at an offset: Mosaic
+    cannot broadcast a ``(1, block_k)`` slice taken at lane offset
+    ``j * block_k > 0``.  Under int8 ``scale`` [1, n_pad] holds the
+    per-feature chunk scales.  ``lead`` indexes the output refs' batch
+    axis (``(0,)``) or nothing.
     """
     bm, n_pad = x.shape
-    k_pad = c.shape[0]
-    nk, nn = k_pad // block_k, n_pad // block_n
+    nk, nn = csq.shape[0], n_pad // block_n
     int8 = precision == "int8"
 
     best = jnp.full((bm,), _BIG, jnp.float32)
@@ -179,39 +246,6 @@ def _tile_argmin(x, c, csq, *, block_k: int, block_n: int, precision: str,
         xsq = jnp.sum(deq * deq, axis=1)
     else:
         xsq = px.sqnorm(x, axis=1)
-    return bidx, best, xsq
-
-
-def _unpack_fused_refs(args, precision: str):
-    """(x, c, csq, t, scale, sums, counts, obj, rest) from positional refs."""
-    if precision == "int8":
-        x_ref, c_ref, csq_ref, t_ref, scale_ref = args[:5]
-        rest = args[5:]
-    else:
-        x_ref, c_ref, csq_ref = args[:3]
-        t_ref = scale_ref = None
-        rest = args[3:]
-    sums_ref, counts_ref, obj_ref = rest[:3]
-    return x_ref, c_ref, csq_ref, t_ref, scale_ref, sums_ref, counts_ref, \
-        obj_ref, rest[3:]
-
-
-def _fused_tile_accumulate(i, x, c, csq, t, scale, sums_ref, counts_ref,
-                           obj_ref, *, m: int, block_m: int, block_k: int,
-                           block_n: int, precision: str, batched: bool):
-    """Process one resident point tile and accumulate into the output refs.
-
-    ``i`` is the point-tile index (python int or tracer); ``batched`` says
-    whether the output refs carry a leading [1] batch axis.
-    """
-    bm = x.shape[0]
-    k_pad = c.shape[0]
-    nk = k_pad // block_k
-    int8 = precision == "int8"
-
-    bidx, best, xsq = _tile_argmin(x, c, csq, block_k=block_k,
-                                   block_n=block_n, precision=precision,
-                                   t=t, scale=scale)
     mind = jnp.maximum(best + xsq, 0.0)
     rows = i * block_m + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
     validb = rows < m                                        # [bm, 1] bool
@@ -225,22 +259,16 @@ def _fused_tile_accumulate(i, x, c, csq, t, scale, sums_ref, counts_ref,
         if int8:
             part = px.intdot(hit.astype(jnp.int8), x,
                              (((0,), (0,)), ((), ())))       # [bk, n_pad] i32
+        elif c.shape[0] > _BLOCK_K * UNROLL_K_TILES:
+            part = px.onehot_dot(hit, x, (((0,), (0,)), ((), ())), precision)
         else:
             part = px.dot(hit.astype(jnp.float32), x,
                           (((0,), (0,)), ((), ())), precision)
-        if batched:
-            sums_ref[0, ksl, :] += part
-            counts_ref[0, :, ksl] += jnp.sum(
-                hit.astype(jnp.float32), axis=0, keepdims=True)
-        else:
-            sums_ref[ksl, :] += part
-            counts_ref[:, ksl] += jnp.sum(
-                hit.astype(jnp.float32), axis=0, keepdims=True)
+        sums_ref[lead + (ksl, slice(None))] += part
+        counts_ref[lead + (slice(None), ksl)] += jnp.sum(
+            hit.astype(jnp.float32), axis=0, keepdims=True)
     contrib = jnp.sum(mind[:, None] * valid, keepdims=True)[0:1, 0:1]
-    if batched:
-        obj_ref[...] += contrib.reshape(1, 1, 1)
-    else:
-        obj_ref[...] += contrib
+    obj_ref[...] += contrib.reshape(obj_ref.shape)
 
 
 def _fused_kernel(*args, m: int, block_m: int, block_k: int, block_n: int,
@@ -255,12 +283,12 @@ def _fused_kernel(*args, m: int, block_m: int, block_k: int, block_n: int,
         counts_ref[...] = jnp.zeros_like(counts_ref)
         obj_ref[...] = jnp.zeros_like(obj_ref)
 
-    _fused_tile_accumulate(
+    _accumulate(
         i, x_ref[...], c_ref[...], csq_ref[...],
         None if t_ref is None else t_ref[...],
         None if scale_ref is None else scale_ref[...],
-        sums_ref, counts_ref, obj_ref, m=m, block_m=block_m, block_k=block_k,
-        block_n=block_n, precision=precision, batched=False)
+        sums_ref, counts_ref, obj_ref, lead=(), m=m, block_m=block_m,
+        block_k=block_k, block_n=block_n, precision=precision)
 
 
 def _fused_dma_kernel(*args, m: int, block_m: int, block_k: int,
@@ -296,10 +324,10 @@ def _fused_dma_kernel(*args, m: int, block_m: int, block_k: int,
             dma(jax.lax.rem(i + 1, 2), i + 1).start()
 
         dma(slot, i).wait()
-        _fused_tile_accumulate(
+        _accumulate(
             i, scratch[slot], c, csq, t, scale, sums_ref, counts_ref,
-            obj_ref, m=m, block_m=block_m, block_k=block_k, block_n=block_n,
-            precision=precision, batched=False)
+            obj_ref, lead=(), m=m, block_m=block_m, block_k=block_k,
+            block_n=block_n, precision=precision)
         return carry
 
     jax.lax.fori_loop(0, num_tiles, body, 0)
@@ -368,22 +396,21 @@ def fused_step_pallas(
     kw = dict(m=m, block_m=block_m, block_k=block_k, block_n=block_n,
               precision="int8" if int8 else precision)
 
+    def whole(shape):
+        """A block that is the whole array, at every grid step."""
+        return pl.BlockSpec(shape, lambda *_: (0,) * len(shape))
+
     if pipeline == "dma":
         num_tiles = bm // block_m
         x_spec = [pl.BlockSpec(memory_space=pl.ANY)]
-        aux_specs = [pl.BlockSpec((k_pad, n_pad), lambda: (0, 0)),
-                     pl.BlockSpec((nk, 1, block_k), lambda: (0, 0, 0))]
+        aux_specs = [whole((k_pad, n_pad)), whole((nk, 1, block_k))]
         if int8:
-            aux_specs += [pl.BlockSpec((nk, 1, block_k), lambda: (0, 0, 0)),
-                          pl.BlockSpec((1, n_pad), lambda: (0, 0))]
+            aux_specs += [whole((nk, 1, block_k)), whole((1, n_pad))]
         sums, counts, obj = pl.pallas_call(
             functools.partial(_fused_dma_kernel, num_tiles=num_tiles, **kw),
             in_specs=x_spec + aux_specs,
-            out_specs=[
-                pl.BlockSpec((k_pad, n_pad), lambda: (0, 0)),
-                pl.BlockSpec((1, k_pad), lambda: (0, 0)),
-                pl.BlockSpec((1, 1), lambda: (0, 0)),
-            ],
+            out_specs=[whole((k_pad, n_pad)), whole((1, k_pad)),
+                       whole((1, 1))],
             out_shape=out_shape,
             scratch_shapes=[
                 pltpu.VMEM((2, block_m, n_pad), xp.dtype),
@@ -395,21 +422,17 @@ def fused_step_pallas(
     else:
         in_specs = [
             pl.BlockSpec((block_m, n_pad), lambda i: (i, 0)),
-            pl.BlockSpec((k_pad, n_pad), lambda i: (0, 0)),
-            pl.BlockSpec((nk, 1, block_k), lambda i: (0, 0, 0)),
+            whole((k_pad, n_pad)),
+            whole((nk, 1, block_k)),
         ]
         if int8:
-            in_specs += [pl.BlockSpec((nk, 1, block_k), lambda i: (0, 0, 0)),
-                         pl.BlockSpec((1, n_pad), lambda i: (0, 0))]
+            in_specs += [whole((nk, 1, block_k)), whole((1, n_pad))]
         sums, counts, obj = pl.pallas_call(
             functools.partial(_fused_kernel, **kw),
             grid=(bm // block_m,),
             in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((k_pad, n_pad), lambda i: (0, 0)),
-                pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            ],
+            out_specs=[whole((k_pad, n_pad)), whole((1, k_pad)),
+                       whole((1, 1))],
             out_shape=out_shape,
             compiler_params=_COMPILER_PARAMS,
             interpret=interpret,
@@ -435,12 +458,12 @@ def _fused_batched_kernel(*args, m: int, block_m: int, block_k: int,
         counts_ref[...] = jnp.zeros_like(counts_ref)
         obj_ref[...] = jnp.zeros_like(obj_ref)
 
-    _fused_tile_accumulate(
+    _accumulate(
         i, x_ref[0], c_ref[0], csq_ref[0],
         None if t_ref is None else t_ref[0],
         None if scale_ref is None else scale_ref[0],
-        sums_ref, counts_ref, obj_ref, m=m, block_m=block_m, block_k=block_k,
-        block_n=block_n, precision=precision, batched=True)
+        sums_ref, counts_ref, obj_ref, lead=(0,), m=m, block_m=block_m,
+        block_k=block_k, block_n=block_n, precision=precision)
 
 
 @functools.partial(
@@ -490,18 +513,23 @@ def fused_step_batched_pallas(
     xp = _pad_to(_pad_to(xs, bm, 1), n_pad, 2)
     cp = _pad_to(_pad_to(cs, k_pad, 1), n_pad, 2)
     nk = k_pad // block_k
+
+    def per_stream(shape):
+        """Stream b's whole ``shape`` block, at every point tile."""
+        return pl.BlockSpec((1,) + shape,
+                            lambda b, i: (b,) + (0,) * len(shape))
+
     inputs = [xp, cp, _lane_tiles(csq, k_pad, block_k, value=_BIG)]
-    tiles_spec = pl.BlockSpec((1, nk, 1, block_k), lambda b, i: (b, 0, 0, 0))
+    tiles_spec = per_stream((nk, 1, block_k))
     in_specs = [
         pl.BlockSpec((1, block_m, n_pad), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, k_pad, n_pad), lambda b, i: (b, 0, 0)),
+        per_stream((k_pad, n_pad)),
         tiles_spec,
     ]
     if int8:
         inputs += [_lane_tiles(t, k_pad, block_k),
                    _pad_to(qx.scale[:, None, :], n_pad, 2)]
-        in_specs += [tiles_spec,
-                     pl.BlockSpec((1, 1, n_pad), lambda b, i: (b, 0, 0))]
+        in_specs += [tiles_spec, per_stream((1, n_pad))]
 
     sums_dtype = jnp.int32 if int8 else jnp.float32
     sums, counts, obj = pl.pallas_call(
@@ -510,11 +538,8 @@ def fused_step_batched_pallas(
                           precision="int8" if int8 else precision),
         grid=(batch, bm // block_m),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k_pad, n_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, k_pad), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0)),
-        ],
+        out_specs=[per_stream((k_pad, n_pad)), per_stream((1, k_pad)),
+                   per_stream((1, 1))],
         out_shape=[
             jax.ShapeDtypeStruct((batch, k_pad, n_pad), sums_dtype),
             jax.ShapeDtypeStruct((batch, 1, k_pad), jnp.float32),

@@ -349,6 +349,25 @@ def fused_step(
     return sums, counts, obj
 
 
+def lloyd_path(op: str, *, impl: str, b: int, m: int, k: int, n: int,
+               precision: str) -> tuple[str, int]:
+    """How a Lloyd step of ``op`` (``'fused'`` or ``'fused_batched'``) at
+    this shape dispatches, decided as :func:`fused_step` and
+    :func:`fused_step_batched` decide it: ``('fused', k tiles)`` where the
+    fused Pallas kernel runs, ``('two_pass', 0)`` where the two-pass path
+    does (a ref impl, an envelope miss, a demoted shape).  ``precision``
+    is concrete."""
+    from repro.kernels import fused_step as fused
+
+    impl = resolve_impl(impl)
+    if impl not in ("pallas", "pallas_interpret") or not fused.fits(k, n) \
+            or _demoted((op, impl, (b, m, k, n), precision)):
+        return "two_pass", 0
+    blocks = autotune.get_blocks(op, backend=_tune_backend(impl), b=b, m=m,
+                                 k=k, n=n, precision=precision)
+    return "fused", fused.k_tiles(k, blocks["block_k"])
+
+
 @functools.partial(jax.jit, static_argnames=("precision",))
 def _fused_step_batched_ref(x, c, *, precision="f32"):
     """Batched two-pass oracle.

@@ -169,6 +169,28 @@ def dot(a: jax.Array, b: jax.Array, dimension_numbers, precision: str):
     return dg(ah, bh) + dg(ah, bl) + dg(al, bh)
 
 
+def onehot_dot(hit: jax.Array, b: jax.Array, dimension_numbers,
+               precision: str):
+    """:func:`dot` of a 0/1 matrix ``hit`` (bool or float) with ``b``.
+
+    ``hit`` is exact in bf16.  Under ``'f32'``, ``b`` is split into three
+    bf16 parts that sum to it (8 significand bits each), and the three
+    single-pass products, each exact and accumulated in f32, stand for
+    ``Precision.HIGHEST``'s six passes, three of which would multiply
+    ``hit``'s all-zero low parts.  Other policies are :func:`dot`'s.
+    """
+    if precision != "f32":
+        return dot(hit.astype(jnp.float32), b, dimension_numbers, precision)
+    h = hit.astype(jnp.bfloat16)
+    b = b.astype(jnp.float32)
+    hi, mid = _split_bf16(b)
+    lo = (b - hi.astype(jnp.float32)
+          - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    dg = lambda y: jax.lax.dot_general(  # noqa: E731
+        h, y, dimension_numbers, preferred_element_type=jnp.float32)
+    return dg(hi) + dg(mid) + dg(lo)
+
+
 def sqnorm(a: jax.Array, axis=-1, keepdims: bool = False) -> jax.Array:
     """``sum(a*a)`` in f32 regardless of storage dtype (norms never bf16)."""
     a = a.astype(jnp.float32)

@@ -11,8 +11,10 @@ Host spans:
 
 * ``repro.fit.dispatch`` — ``fit()`` entry until its jitted call returns
   (args ``strategy``, ``n_chunks``; and where the fit runs as one jitted
-  program, ``gather``: ``packed`` or ``rows``, and ``g``, the points one
-  gathered row holds);
+  program, ``gather``: ``packed`` or ``rows``, ``g``, the points one
+  gathered row holds, ``lloyd``: ``fused`` where the fused Pallas Lloyd
+  kernel runs or ``two_pass``, and ``k_tiles``, the lane tiles of k that
+  kernel walks, 0 on the two-pass path);
 * ``repro.fit.collect`` — reading the fit's result to the host;
 * ``repro.serve.take`` — the batcher waiting and lingering for requests;
 * ``repro.serve.launch`` — one launch, pack to scatter (args ``launch``,
@@ -21,6 +23,10 @@ Host spans:
   (``device_put`` and the jitted assign call), ``repro.serve.fetch``
   (reading ids and distances to the host) and ``repro.serve.scatter``
   (resolving each request's future), which carry its ``launch``.
+
+Program counter: ``FitResult.seed_steps``, the D^2 steps the K-means++
+seeding loops ran over the fit's chunks (k for each chunk whose step
+seeded; per chunk in ``ChunkInfo.seed_steps``).
 
 Device scopes: ``repro.fit.sample`` (drawing a chunk's rows and gathering
 them, and the dataset's packed copy they are gathered from),

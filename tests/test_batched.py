@@ -26,7 +26,7 @@ from repro.core.kmeanspp import seed, seed_batched
 from repro.data.synthetic import GMMSpec, gmm_dataset
 from repro.kernels import ops
 from repro.kernels.fused_step import (
-    LEGACY_MAX_K, LEGACY_MAX_N, MAX_K, MAX_N, fits, fits_batched,
+    LEGACY_MAX_K, LEGACY_MAX_N, MAX_N, fits, fits_batched, max_k,
     fused_step_batched_pallas,
 )
 
@@ -100,7 +100,8 @@ def test_batched_fused_kernel_matches_two_pass(B, m, n, k):
         # Beyond the historical single-chunk envelope — the k-tiled argmin
         # rewrite widened fits() to cover these shapes in one kernel too.
         assert fits(k, n)
-    assert not fits(MAX_K + 1, n)        # the widened wall still exists
+    assert fits(max_k(n), n)
+    assert not fits(max_k(n) + 1, n)     # the widened wall still exists
     assert not fits(k, MAX_N + 1)
     s_p, n_p, o_p = fused_step_batched_pallas(x, c, interpret=True)
     s_r, n_r, o_r = ops._fused_step_batched_ref(x, c)

@@ -211,11 +211,14 @@ def test_fused_step_fallback_honors_ref_chunked(monkeypatch):
     w = jnp.ones((x.shape[0],))
     ops.fused_step(x, c, weights=w, impl="ref_chunked")
     assert seen == ["ref_chunked"]
-    # envelope miss (k > MAX_K) also keeps the bounded-working-set path
+    # envelope miss (c + sums blocks past the resident VMEM) also keeps the
+    # bounded-working-set path
     seen.clear()
-    kbig = 130
-    cbig = jax.random.normal(jax.random.PRNGKey(0), (kbig, 2000))
-    xbig = jax.random.normal(jax.random.PRNGKey(1), (64, 2000))
+    kbig, nbig = 8192, 256
+    from repro.kernels.fused_step import fits
+    assert not fits(kbig, nbig)
+    cbig = jax.random.normal(jax.random.PRNGKey(0), (kbig, nbig))
+    xbig = jax.random.normal(jax.random.PRNGKey(1), (64, nbig))
     ops.fused_step(xbig, cbig, impl="ref_chunked")
     assert seen == ["ref_chunked"]
 
@@ -505,9 +508,8 @@ def test_autotune_candidates_cover_k256_and_dma(clean_autotune):
     assert any(c["pipeline"] == "dma" for c in cands)
     from repro.kernels import fused_step as fused
     for c in cands:
-        k_pad, n_pad, _, _ = fused._batched_tiles(
-            256, 20, c["block_k"], c["block_n"])
-        assert k_pad * n_pad <= fused._MAX_KN_ELEMS, c
+        assert fused.resident_bytes(256, 20, c["block_k"], c["block_n"]) \
+            <= fused.RESIDENT_BYTES, c
 
 
 def test_unknown_pipeline_rejected():
@@ -667,9 +669,9 @@ def test_ref_point_norms_of_stored_points(precision):
 def test_pallas_envelope_miss_is_recorded(clean_demotions, batched):
     """A Pallas request routed to the two-pass path by the envelope shows
     in kernel_demotions(), naming op and shape; a ref request does not."""
-    from repro.kernels.fused_step import MAX_K
+    from repro.kernels.fused_step import max_k
 
-    k, n, m = MAX_K + 8, 8, 64
+    k, n, m = max_k(8) + 8, 8, 64
     x = jax.random.normal(jax.random.PRNGKey(0), (m, n))
     c = jax.random.normal(jax.random.PRNGKey(1), (k, n))
     if batched:

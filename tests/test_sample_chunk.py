@@ -112,7 +112,7 @@ def test_the_planned_program_takes_the_plans_choice(method, n, gather):
     program = strategies.plan(method, cfg, as_source(X),
                               jax.random.PRNGKey(0))
     assert program.kwargs["gather"] == gather
-    assert program.gather_args()["gather"] == gather
+    assert program.dispatch_args()["gather"] == gather
 
 
 # ---------------------------------------------------------------------------

@@ -201,7 +201,8 @@ def test_a_profile_of_fit_holds_its_dispatch_and_collect_spans(tmp_path):
     X = jax.random.normal(jax.random.PRNGKey(0), (2000, 4))
     # 4 features take 4 lanes of a packed row: 32 points to a row
     assert _dispatch_and_collect(tmp_path, X, "batched") == {
-        "strategy": "batched", "n_chunks": 4, "gather": "packed", "g": 32}
+        "strategy": "batched", "n_chunks": 4, "gather": "packed", "g": 32,
+        "lloyd": "two_pass", "k_tiles": 0}
 
 
 @pytest.mark.parametrize("n, gather, g", [(28, "packed", 4),
@@ -212,3 +213,23 @@ def test_the_dispatch_span_says_how_chunk_rows_are_gathered(tmp_path, n,
     X = jax.random.normal(jax.random.PRNGKey(1), (1000, n))
     args = _dispatch_and_collect(tmp_path, X, "sequential")
     assert (args["gather"], args["g"]) == (gather, g)
+
+
+@pytest.mark.parametrize("method", ["batched", "sequential"])
+@pytest.mark.parametrize("impl, k, n, lloyd, k_tiles", [
+    ("ref", 25, 28, "two_pass", 0),
+    ("pallas_interpret", 25, 28, "fused", 1),
+    ("pallas_interpret", 25, 768, "fused", 1),
+    ("pallas_interpret", 4096, 128, "fused", 8),
+    ("pallas_interpret", 8320, 128, "two_pass", 0),    # envelope miss
+])
+def test_the_dispatch_span_says_which_lloyd_path_runs(method, impl, k, n,
+                                                      lloyd, k_tiles):
+    from repro.api import as_source, strategies
+
+    X = np.zeros((16384, n), np.float32)
+    cfg = BigMeansConfig(k=k, s=8320, n_chunks=4, batch=2, impl=impl)
+    program = strategies.plan(method, cfg, as_source(X),
+                              jax.random.PRNGKey(0))
+    args = program.dispatch_args()
+    assert (args["lloyd"], args["k_tiles"]) == (lloyd, k_tiles)

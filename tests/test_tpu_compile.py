@@ -119,12 +119,28 @@ def test_fused_step_k256_compiles(one_chip, pipeline, precision):
 @pytest.mark.parametrize("pipeline,k,n,block_m", [
     ("dma", 256, 4096, 256),
     ("blocks", 1024, 1024, 512),
+    ("blocks", 8192, 128, 256),
 ])
 def test_fused_step_envelope_corners_compile(one_chip, pipeline, k, n,
                                              block_m):
     """The widest shapes ``fits`` admits stay inside the kernel's VMEM."""
     text = _compile(_fused("f32", pipeline=pipeline, block_m=block_m),
                     *_fused_args(one_chip, 8192, n, k, "f32"))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16", "int8"])
+def test_fused_step_batched_k4096_compiles(one_chip, precision):
+    """SIFT1M's codebook shape: 8 lane tiles of 512 centroids, the 2 MiB
+    centroid and sums blocks resident."""
+    if precision == "int8":
+        fn = lambda q, scale, c: fused_step_batched_pallas(  # noqa: E731
+            px.QuantizedChunk(q, scale), c)
+    else:
+        fn = lambda x, c: fused_step_batched_pallas(  # noqa: E731
+            x, c, precision=precision)
+    text = _compile(fn, *_fused_args(one_chip, S, 128, 4096, precision,
+                                     batch=8))
     assert "tpu_custom_call" in text
 
 
@@ -171,6 +187,20 @@ def test_packed_gather_fit_compiles_at_hepmass_size(one_chip, precision):
     assert _chunk_gathers(compiled.as_text()) == {"1,128"}
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6 * GIB
+
+
+def test_codebook_fit_compiles_at_sift1m_size(one_chip):
+    """The in-core fit of a k = 4096 codebook on 1M x 128 runs the fused
+    kernel, and its program's temporaries stay far below the chip: XLA
+    fuses K-means++'s [B, s, k] start into its minimum."""
+    X = jax.ShapeDtypeStruct((1_000_000, 128), jnp.float32,
+                             sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    kw = dict(_fit_kwargs("f32"), k=4096, gather="rows")
+    compiled = incore.batched_local.lower(X, key, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * GIB
 
 
 def _mesh(topo, axis):
